@@ -15,7 +15,7 @@ from repro import checkpoint
 from repro.configs import PFELSConfig, reduced_config
 from repro.core.channel import scaled_channel
 from repro.data import make_lm_sequences
-from repro.launch.mesh import make_host_mesh, use_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_pfels_train_step
 from repro.models import transformer as T
 
@@ -53,7 +53,7 @@ def main():
                         channel=scaled_channel(d))
     step = make_pfels_train_step(cfg, pfels, d, mesh)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_j = jax.jit(step)
         p = params
         t0 = time.time()

@@ -17,10 +17,12 @@ from repro.configs.paper_models import BENCH_CNN_CIFAR
 from repro.core.channel import scaled_channel
 from repro.data import make_federated_classification
 from repro.fl import Trainer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cnn
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=200)
     ap.add_argument("--epsilon", type=float, default=1.5)

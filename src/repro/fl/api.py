@@ -258,7 +258,10 @@ class Trainer:
             return self._run_streamed(state, data_x, data_y, t)
         fn = self._run_cache.get(t)
         if fn is None:
-            fn = jax.jit(lambda s, x, y: self._run_impl(s, x, y, t))
+            # named, so compile logs and profiles can find the program
+            def trainer_run(s, x, y):
+                return self._run_impl(s, x, y, t)
+            fn = jax.jit(trainer_run)
             self._run_cache[t] = fn
         return fn(state, data_x, data_y)
 

@@ -39,8 +39,8 @@ import numpy as np
 
 BACKENDS = ("resident", "streamed")
 
-# lane keys have the shape/dtype of a raw threefry key (jax >= 0.4.37
-# floor; conftest pins x64 off)
+# lane keys have the shape/dtype of a raw threefry key (conftest pins
+# x64 off)
 _KEY_SHAPE = tuple(jax.random.PRNGKey(0).shape)
 _KEY_DTYPE = jax.random.PRNGKey(0).dtype
 

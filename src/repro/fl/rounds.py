@@ -39,7 +39,7 @@ from repro.core import aggregation, channel, channels, compressors, privacy
 from repro.fl import algorithms
 from repro.fl.client import local_train, model_update
 from repro.kernels.pfels_transmit import ref as transmit_ref
-from repro.launch.mesh import make_cohort_mesh, shard_map_compat
+from repro.launch.mesh import make_cohort_mesh
 from repro.sharding import rules
 
 _COHORT_AXES = ("pod", "data")
@@ -291,11 +291,13 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
                 energy = jnp.asarray(0.0, jnp.float32)
             return flat_l, losses_l, tx_l, delta_hat, energy
 
-        cohort_apply = shard_map_compat(
-            cohort_body, mesh,
+        # replication checking off: the cohort path communicates via
+        # explicit psums
+        cohort_apply = jax.shard_map(
+            cohort_body, mesh=mesh,
             in_specs=(P(), spec_c, spec_c, spec_c, spec_c, spec_c, spec_c,
                       spec_c, spec_c, P(), P(), P(), P()),
-            out_specs=(spec_c, spec_c, spec_c, P(), P()))
+            out_specs=(spec_c, spec_c, spec_c, P(), P()), check_vma=False)
 
     def cohort_core(params, p_sel, cx, cy, ks, res_sel=None,
                     prev_delta=None, chan_carry=None, sel=None,

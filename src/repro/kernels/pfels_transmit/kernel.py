@@ -10,8 +10,11 @@ from the kernel: each grid step loads an (r, block) tile of the updates,
 masks it, reduces over clients with the per-client receive coefficients
 (VPU multiply + sublane reduction; an MXU matvec at large r), adds the
 pre-scattered channel noise, and accumulates the transmit energy
-sum_i tx_i^2 ||m * Delta_i||^2 into a (1, 1) output across the sequential
-TPU grid (the same cross-step reduction idiom as clip_norm).
+sum_i tx_i^2 ||m * Delta_i||^2 into a (1, 1) output block across the
+sequential TPU grid. The accumulator is read and written as a whole (1, 1)
+vector (``e_ref[...]``), never as a scalar element: Mosaic cannot store a
+scalar to VMEM, so an ``e_ref[0, 0]`` store compiles only in interpret
+mode.
 
 The whole wireless-scenario matrix runs in-tile (DESIGN.md §12):
 
@@ -28,6 +31,11 @@ Two passes, like clip_norm: pass 1 (optional, only when a transmit clip is
 set) accumulates per-client squared norms over the full d; the host turns
 them into clip scales and per-client coefficients; pass 2 does the fused
 combine.
+
+Both entry points take ``interpret`` as a required argument. Only
+``ops.fused_pipeline`` resolves it (the compiled kernel on a TPU backend,
+the Pallas interpreter elsewhere), so no caller can fall back to the
+interpreter on a TPU by default.
 """
 from __future__ import annotations
 
@@ -73,7 +81,7 @@ def _combine_kernel(g_ref, tx_ref, txm_ref, u_ref, m_ref, z_ref,
 
     @pl.when(i == 0)
     def _init():
-        e_ref[0, 0] = jnp.zeros((), jnp.float32)
+        e_ref[...] = jnp.zeros_like(e_ref)
 
     g_eff = jnp.sum(g_ref[...].astype(jnp.float32), axis=1, keepdims=True)
     tx = tx_ref[...].astype(jnp.float32)
@@ -82,13 +90,14 @@ def _combine_kernel(g_ref, tx_ref, txm_ref, u_ref, m_ref, z_ref,
     um = u_ref[...].astype(jnp.float32) * m_ref[...].astype(jnp.float32)
     y_ref[...] = (jnp.sum(um * rxw, axis=0, keepdims=True)
                   + z_ref[...]).astype(y_ref.dtype)
-    e_ref[0, 0] += jnp.sum((tx * tx * txm)
-                           * jnp.sum(um * um, axis=1, keepdims=True))
+    e_ref[...] += jnp.sum((tx * tx * txm)
+                          * jnp.sum(um * um, axis=1, keepdims=True),
+                          axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def client_sumsq(updates: jnp.ndarray, *, block: int = 4096,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool) -> jnp.ndarray:
     """updates: (r, d_pad) with d_pad % block == 0. Returns (r, 1) f32
     per-client squared l2 norms (zero-padding is norm-neutral)."""
     r, d_pad = updates.shape
@@ -107,7 +116,7 @@ def client_sumsq(updates: jnp.ndarray, *, block: int = 4096,
 def fused_combine(updates: jnp.ndarray, mask: jnp.ndarray,
                   noise_dense: jnp.ndarray, gains_mat: jnp.ndarray,
                   tx: jnp.ndarray, tx_mask: jnp.ndarray, *,
-                  block: int = 4096, interpret: bool = True):
+                  block: int = 4096, interpret: bool):
     """updates: (r, d_pad); mask/noise_dense: (1, d_pad); gains_mat:
     (r, M) per-antenna true gains; tx/tx_mask: (r, 1). d_pad % block == 0.
     Returns (y_dense (1, d_pad), energy (1, 1))."""

@@ -33,17 +33,15 @@ from repro.configs.base import PFELSConfig
 from repro.launch import inputs as I
 from repro.launch import steps as S
 from repro.launch.hlo_analysis import (collective_bytes, model_flops,
-                                       normalize_cost as
-                                       hlo_analysis_normalize,
                                        roofline_terms)
 from repro.launch.hlo_cost import analyze_hlo
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as T
 from repro.sharding.rules import tree_shardings
 
 
 def _param_shardings(cfg, mesh):
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         shapes = T.init_shapes(cfg)
         logical = T.logical_axes(cfg)
     return shapes, tree_shardings(mesh, logical, shapes)
@@ -70,7 +68,7 @@ def lower_and_compile(cfg, shape, mesh, pfels, *, donate=True):
     n_params = sum(x.size for x in jax.tree.leaves(param_shapes))
 
     n_pods = mesh.shape.get("pod", 1)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             batch = I.train_batch_specs(cfg, shape, mesh)
             step = S.make_pfels_train_step(cfg, pfels, n_params, mesh)
@@ -159,7 +157,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
                                                        pfels)
     t1 = time.time()
     mem = compiled.memory_analysis()
-    raw_cost = hlo_analysis_normalize(compiled.cost_analysis())
+    raw_cost = compiled.cost_analysis()
     raw_coll = collective_bytes(compiled.as_text())
 
     if analyze_loops:

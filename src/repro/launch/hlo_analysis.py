@@ -80,16 +80,7 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
     return out
 
 
-def normalize_cost(cost) -> Dict:
-    """``Compiled.cost_analysis()`` returns a dict on jax >= 0.5 but a
-    one-element list of dicts on 0.4.x — accept both."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def roofline_terms(cost: Dict, coll: Dict, n_chips: int) -> Dict:
-    cost = normalize_cost(cost)
     flops = float(cost.get("flops", 0.0))
     bytes_acc = float(cost.get("bytes accessed", 0.0))
     t_compute = flops / PEAK_FLOPS

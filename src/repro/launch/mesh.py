@@ -2,39 +2,14 @@
 state; call the functions."""
 from __future__ import annotations
 
-import enum
-
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 has explicit mesh axis types
-    from jax.sharding import AxisType
-    _HAS_AXIS_TYPES = True
-except ImportError:  # jax 0.4.x: every axis is implicitly Auto
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-    _HAS_AXIS_TYPES = False
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on jax >= 0.6,
-    the ``with mesh:`` global-mesh context on 0.4.x (where pjit resolves
-    unspecified shardings against the thread-local physical mesh)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(devices: np.ndarray, axes) -> Mesh:
-    """Mesh with Auto axis types where the pinned jax supports them, plain
-    Mesh otherwise (pre-0.5 Mesh has no ``axis_types`` kwarg and treats all
-    axes as Auto anyway)."""
-    if _HAS_AXIS_TYPES:
-        return Mesh(devices, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return Mesh(devices, axes)
+    """Mesh over ``devices`` with every axis ``AxisType.Auto``."""
+    return Mesh(devices, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -86,19 +61,3 @@ def make_cohort_mesh(r: int, *, devices=None) -> Mesh:
     pod, data = cohort_shape(r, len(devices))
     dev = np.array(devices[: pod * data]).reshape(pod, data)
     return make_mesh(dev, ("pod", "data"))
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (jax.shard_map on new jax,
-    jax.experimental.shard_map on the 0.4.x floor), with replication
-    checking off — the cohort path communicates via explicit psums."""
-    try:
-        from jax import shard_map as sm          # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:                             # check_rep -> check_vma
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)

@@ -19,21 +19,45 @@ import argparse
 import dataclasses
 import json
 import time
+from typing import Any
 
 import jax
 
 from repro.configs.base import CompressionSchedule, PFELSConfig
-from repro.configs.paper_models import BENCH_CNN_CIFAR, BENCH_MLP
+from repro.configs.paper_models import (BENCH_CNN_CIFAR, BENCH_MLP,
+                                        PAPER_RESNET18_FEMNIST,
+                                        PAPER_VGG11_CIFAR10)
 from repro.core.channel import scaled_channel
 from repro.core.channels import list_channel_models
 from repro.core.compressors import list_compressors
 from repro.data import make_federated_classification, make_population_source
-from repro.fl import Trainer, list_algorithms
+from repro.fl import Trainer, TrainState, list_algorithms
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cnn
 
+# --model choices: the CPU-scale stand-ins and the paper's full-width
+# models (configs/paper_models.py)
+MODELS = {"mlp": BENCH_MLP, "cnn": BENCH_CNN_CIFAR,
+          PAPER_VGG11_CIFAR10.name: PAPER_VGG11_CIFAR10,
+          PAPER_RESNET18_FEMNIST.name: PAPER_RESNET18_FEMNIST}
 
-def run_simulation(args):
-    model_cfg = BENCH_CNN_CIFAR if args.model == "cnn" else BENCH_MLP
+
+@dataclasses.dataclass
+class Simulation:
+    """Everything :func:`run_simulation` drives: the trainer, its initial
+    state, and the federated train/test data."""
+    cfg: PFELSConfig
+    d: int
+    trainer: Trainer
+    state: TrainState
+    x: Any
+    y: Any
+    xt: Any
+    yt: Any
+
+
+def build_simulation(args) -> Simulation:
+    model_cfg = MODELS[args.model]
     key = jax.random.PRNGKey(args.seed)
     params = cnn.init_cnn(key, model_cfg)
     d = sum(p.size for p in jax.tree.leaves(params))
@@ -76,19 +100,36 @@ def run_simulation(args):
             alpha=args.dirichlet_alpha)
     loss_fn = lambda p, b: cnn.cnn_loss(p, model_cfg, b)
     trainer = Trainer(cfg, loss_fn, params)
-    state = trainer.init(key)
+    return Simulation(cfg=cfg, d=d, trainer=trainer,
+                      state=trainer.init(key), x=x, y=y, xt=xt, yt=yt)
+
+
+def run_simulation(args):
+    """Train ``args.rounds`` rounds in chunks of ``args.eval_every``
+    (one ``Trainer.run`` call each), evaluating after every chunk.
+    Returns ``(out, sim, state)``: the JSON report (also written to
+    ``args.out``), the :class:`Simulation` it built, and the final
+    ``TrainState``."""
+    sim = build_simulation(args)
+    cfg, d, trainer, state = sim.cfg, sim.d, sim.trainer, sim.state
     history = []
     energy_total = 0.0
     t0 = time.time()
     while int(state.round) < cfg.rounds:
         chunk = min(args.eval_every, cfg.rounds - int(state.round))
-        state, m = trainer.run(state, x, y, rounds=chunk)
+        t_run = time.perf_counter()
+        state, m = jax.block_until_ready(
+            trainer.run(state, sim.x, sim.y, rounds=chunk))
+        run_s = time.perf_counter() - t_run
         energy_total += float(m["energy"].sum())
-        tl, acc = trainer.evaluate(state, xt, yt)
+        tl, acc = trainer.evaluate(state, sim.xt, sim.yt)
         history.append({"round": int(state.round) - 1,
                         "train_loss": float(m["train_loss"][-1]),
                         "test_acc": acc, "energy_cum": energy_total,
-                        "subcarriers": int(m["subcarriers"][-1])})
+                        "subcarriers": int(m["subcarriers"][-1]),
+                        "run_s": run_s,
+                        "per_round": {k: m[k].tolist() for k in
+                                      ("train_loss", "beta", "energy")}})
         print(f"[{cfg.algorithm}] round {int(state.round) - 1:4d} loss="
               f"{float(m['train_loss'][-1]):.3f} acc={acc:.3f} "
               f"energy={energy_total:.3e}", flush=True)
@@ -108,16 +149,18 @@ def run_simulation(args):
     if args.out:
         with open(args.out, "w") as f:
             json.dump(out, f, indent=2)
-    return out
+    return out, sim, state
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="simulation",
                     choices=["simulation"])
     ap.add_argument("--algorithm", default="pfels",
                     choices=list_algorithms())
-    ap.add_argument("--model", default="mlp", choices=["mlp", "cnn"])
+    ap.add_argument("--model", default="mlp", choices=sorted(MODELS),
+                    help="model config: the CPU-scale 'mlp'/'cnn' "
+                         "stand-ins or a full-width paper model")
     ap.add_argument("--clients", type=int, default=100)
     ap.add_argument("--sampled", type=int, default=16)
     ap.add_argument("--per-client", type=int, default=40)
@@ -189,8 +232,12 @@ def main():
     ap.add_argument("--eval-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
-    args = ap.parse_args()
-    run_simulation(args)
+    return ap
+
+
+def main():
+    enable_compile_cache()
+    run_simulation(build_parser().parse_args())
 
 
 if __name__ == "__main__":

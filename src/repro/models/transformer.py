@@ -21,27 +21,6 @@ from repro.models import attention, layers, mamba2, moe as moe_lib
 from repro.sharding.rules import constraint
 
 
-# ------------------------------------------------------------------ compat
-
-@jax.custom_vjp
-def _opt_barrier(x):
-    """``lax.optimization_barrier`` with the barrier-on-cotangents VJP the
-    pinned jax (0.4.x) lacks — newer jax defines exactly this rule, so the
-    shim keeps forward AND backward carries pinned against hoisting."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-_opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
 # ---------------------------------------------------------------- positions
 
 def sinusoidal_pos(positions, d):
@@ -282,7 +261,7 @@ def forward_train(params, cfg: ModelConfig, batch, *, remat: bool = True,
         # The barrier stops XLA hoisting a whole-stack f32 convert of the
         # saved carries out of the backward loop (a 2x memory pessimisation
         # observed on the CPU backend).
-        x = _opt_barrier(x)
+        x = jax.lax.optimization_barrier(x)
         x = constraint(x, "batch", None, "tensor")
         aux_sum = jnp.zeros((), jnp.float32)
         for i, kind in enumerate(pat):

@@ -12,7 +12,7 @@ from repro.configs.shapes import InputShape
 from repro.launch import inputs as I
 from repro.launch import steps as S
 from repro.launch.hlo_analysis import collective_bytes, roofline_terms
-from repro.launch.mesh import make_host_mesh, use_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.sharding.rules import tree_shardings
 
@@ -23,7 +23,7 @@ LONG_S = InputShape("long_500k", 512, 1, "decode")  # triggers window mode
 
 
 def _params_in(cfg, mesh):
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         shapes = T.init_shapes(cfg)
         logical = T.logical_axes(cfg)
     sh = tree_shardings(mesh, logical, shapes)
@@ -43,7 +43,7 @@ def test_lower_compile_all_kinds(arch, shape):
     pfels = PFELSConfig(num_clients=100, compression_ratio=0.5, epsilon=2.0,
                         local_steps=1)
     params_in = _params_in(cfg, mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             batch = I.train_batch_specs(cfg, shape, mesh)
             d = sum(x.size for x in jax.tree.leaves(params_in))

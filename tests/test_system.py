@@ -80,7 +80,7 @@ def test_pfels_energy_below_wfl_p(fl_setting):
 def test_production_step_numerics():
     """The pod-scale PFELS train step (single-client path) on a reduced
     arch: params stay finite and loss is reasonable."""
-    from repro.launch.mesh import make_host_mesh, use_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import make_pfels_train_step
     cfg = reduced_config("phi3-mini-3.8b")
     mesh = make_host_mesh((1, 1), ("data", "model"))
@@ -95,7 +95,7 @@ def test_production_step_numerics():
         "tokens": jax.random.randint(key, (4, 32), 0, cfg.vocab_size),
         "labels": jax.random.randint(key, (4, 32), 0, cfg.vocab_size),
     }
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_j = jax.jit(step)
         p2, m = step_j(params, batch, jax.random.fold_in(key, 1))
         p3, m2 = step_j(p2, batch, jax.random.fold_in(key, 2))
@@ -107,7 +107,7 @@ def test_production_step_numerics():
 def test_production_grad_accum_equivalence():
     """grad_accum=2 gives the same update direction as accum=1 (same data,
     sigma0~0, p=1 so masking is dense)."""
-    from repro.launch.mesh import make_host_mesh, use_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import make_pfels_train_step
     from repro.configs.base import ChannelConfig
     cfg = dataclasses.replace(reduced_config("mamba2-130m"),
@@ -124,7 +124,7 @@ def test_production_grad_accum_equivalence():
         "labels": jax.random.randint(key, (4, 32), 0, cfg.vocab_size),
     }
     outs = []
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for accum in (1, 2):
             pf = PFELSConfig(grad_accum=accum, **base)
             step = jax.jit(make_pfels_train_step(cfg, pf, d, mesh))
@@ -138,7 +138,7 @@ def test_production_tau_local_steps():
     """tau > 1 production step (Alg. 2 lines 6-10 at pod scale): runs,
     stays finite, and the local update differs from the tau=1 gradient
     step (multiple sequential SGD steps)."""
-    from repro.launch.mesh import make_host_mesh, use_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import make_pfels_train_step
     from repro.configs.base import ChannelConfig
     cfg = dataclasses.replace(reduced_config("phi3-mini-3.8b"),
@@ -153,7 +153,7 @@ def test_production_tau_local_steps():
         "labels": jax.random.randint(key, (8, 32), 0, cfg.vocab_size),
     }
     outs = []
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for tau in (1, 4):
             pf = PFELSConfig(num_clients=100, clients_per_round=1,
                              compression_ratio=1.0, epsilon=1e9,

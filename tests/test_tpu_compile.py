@@ -1,0 +1,102 @@
+"""Compile the transmit kernels, and a small round around them, for a
+described TPU v5e with no chip attached.
+
+Interpret mode runs Pallas kernels that Mosaic refuses (an ``e_ref[0, 0]``
+scalar store to VMEM compiled in interpret mode only). Compiling for the
+described chip shows such refusals, and tiling or VMEM limits, with no
+chip time. The topology is described inside a module fixture, never at
+import, so every test-runner worker collects the same tests and only the
+worker that runs this file loads the TPU compiler.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.pfels_transmit.kernel import client_sumsq, fused_combine
+
+BLOCK = 4096
+D_PAD = 2251 * BLOCK     # the paper's VGG-11 update, d ~ 9.2M, in tiles
+
+
+@pytest.fixture(scope="module")
+def topo():
+    # the TPU compiler otherwise writes its logs to a fixed directory
+    # under /tmp, outside the checkout
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """Compiles for a described chip are written to the persistent cache
+    but cannot be read back without the chip; keep the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+
+
+def _spec(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("r,m_ant", [(8, 1), (32, 1), (32, 4), (256, 1)])
+def test_fused_combine_compiles_for_v5e(one_chip, no_compile_cache, r,
+                                        m_ant):
+    args = (_spec((r, D_PAD), one_chip), _spec((1, D_PAD), one_chip),
+            _spec((1, D_PAD), one_chip), _spec((r, m_ant), one_chip),
+            _spec((r, 1), one_chip), _spec((r, 1), one_chip))
+    compiled = fused_combine.lower(*args, block=BLOCK,
+                                   interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    y, e = compiled.out_info
+    assert (y.shape, e.shape) == ((1, D_PAD), (1, 1))
+
+
+def test_client_sumsq_compiles_for_v5e(one_chip, no_compile_cache):
+    compiled = client_sumsq.lower(_spec((32, D_PAD), one_chip), block=BLOCK,
+                                  interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (32, 1)
+
+
+def test_trainer_step_compiles_for_v5e(one_chip, no_compile_cache,
+                                       monkeypatch):
+    """The whole default round (fused kernel, resident bank) at a small
+    width. ``fused_pipeline`` picks the compiled kernel from
+    ``jax.default_backend()``, which is the CPU here, so the test steers
+    it to the TPU branch."""
+    from repro.configs import PFELSConfig
+    from repro.configs.paper_models import BENCH_MLP
+    from repro.fl import Trainer
+    from repro.models import cnn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = cnn.init_cnn(jax.random.PRNGKey(0), BENCH_MLP)
+    cfg = PFELSConfig(num_clients=20, clients_per_round=8, local_steps=2,
+                      rounds=1, transmit_clip=0.5)
+    trainer = Trainer(cfg, lambda p, b: cnn.cnn_loss(p, BENCH_MLP, b),
+                      params)
+    put = lambda s: _spec(s.shape, one_chip, s.dtype)
+    state = jax.tree.map(put, jax.eval_shape(trainer.init,
+                                             jax.random.PRNGKey(0)))
+    x = _spec((20, 10, 1, 8, 8), one_chip)
+    y = _spec((20, 10), one_chip, jnp.int32)
+    hlo = trainer.step.lower(state, x, y).compile().as_text()
+    # both passes: the clip's sum of squares and the fused combine
+    assert hlo.count("tpu_custom_call") >= 2

