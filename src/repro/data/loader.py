@@ -136,11 +136,17 @@ def prefetch_cohorts(source: CohortSource, sels: Iterable,
 
     def worker():
         try:
-            for sel in sels:
+            for i, sel in enumerate(sels):
                 if stop.is_set():
                     return
-                cx, cy = source.cohort(sel)
-                if not _put((put(cx), put(cy))):
+                # the profiler's span of one cohort: ``round`` is its
+                # index in ``sels``, ``bytes`` what goes to the device
+                with jax.profiler.TraceAnnotation("loader.cohort",
+                                                  round=i) as span:
+                    cx, cy = source.cohort(sel)
+                    span.set_metadata(bytes=int(cx.nbytes + cy.nbytes))
+                    staged = (put(cx), put(cy))
+                if not _put(staged):
                     return
         except BaseException as e:      # surfaced on the consumer side
             _put(_PrefetchError(e))

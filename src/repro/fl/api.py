@@ -181,25 +181,27 @@ class Trainer:
 
     def _advance(self, state: TrainState, n: int, params, bank,
                  prev_delta, ledger, chan) -> TrainState:
-        return TrainState(
-            params=params, power_limits=state.power_limits,
-            bank=bank, prev_delta=prev_delta,
-            key=jax.random.fold_in(state.key, n),
-            round=state.round + n, ledger=ledger, chan=chan)
+        with jax.named_scope("pfels.cohort"):
+            return TrainState(
+                params=params, power_limits=state.power_limits,
+                bank=bank, prev_delta=prev_delta,
+                key=jax.random.fold_in(state.key, n),
+                round=state.round + n, ledger=ledger, chan=chan)
 
     def _spend(self, ledger, metrics):
         """Ledger update + the uniform ``eps_round`` metric. Whether the
         algorithm spends budget is static config, so non-DP schemes carry
         the ledger through untouched (their totals stay (0.0, 0.0) — the
         empty-ledger contract)."""
-        if self.algorithm.privacy_spend is None:
-            eps_round = jnp.zeros((), jnp.float32)
-        else:
-            eps_round = jnp.asarray(
-                self.algorithm.privacy_spend(self.cfg, metrics["beta"],
-                                             self.d),
-                jnp.float32)
-            ledger = privacy.ledger_spend(ledger, eps_round)
+        with jax.named_scope("pfels.ledger"):
+            if self.algorithm.privacy_spend is None:
+                eps_round = jnp.zeros((), jnp.float32)
+            else:
+                eps_round = jnp.asarray(
+                    self.algorithm.privacy_spend(self.cfg, metrics["beta"],
+                                                 self.d),
+                    jnp.float32)
+                ledger = privacy.ledger_spend(ledger, eps_round)
         return ledger, dict(metrics, eps_round=eps_round)
 
     # ------------------------------------------------------------- loops
@@ -213,18 +215,20 @@ class Trainer:
         absolute round counter) and ``eps_spent`` (the ledger's running
         sum) feed the CompressionSchedule inside the compiled body
         (DESIGN.md §13) — traced scalars, never a host round-trip."""
-        ks = rounds.split_round_key(round_key)
-        sel = rounds.sample_cohort(ks[rounds.ROUND_KEY_LANES["selection"]],
-                                   self.cfg.num_clients,
-                                   self.cfg.clients_per_round)
-        res_sel = self.bank.gather(bank, sel)
+        with jax.named_scope("pfels.cohort"):
+            ks = rounds.split_round_key(round_key)
+            sel = rounds.sample_cohort(
+                ks[rounds.ROUND_KEY_LANES["selection"]],
+                self.cfg.num_clients, self.cfg.clients_per_round)
+            res_sel = self.bank.gather(bank, sel)
+            p_sel, cx, cy = power_limits[sel], data_x[sel], data_y[sel]
         new_params, metrics, new_res_sel, delta_hat, new_chan = \
-            self._cohort_core(
-                params, power_limits[sel], data_x[sel], data_y[sel], ks,
-                res_sel, prev_delta, chan, sel, t, eps_spent)
-        lanes = bank_lib.cohort_lane_keys(
-            ks[rounds.ROUND_KEY_LANES["bank"]], sel)
-        new_bank = self.bank.scatter(bank, sel, new_res_sel, lanes)
+            self._cohort_core(params, p_sel, cx, cy, ks, res_sel,
+                              prev_delta, chan, sel, t, eps_spent)
+        with jax.named_scope("pfels.bank"):
+            lanes = bank_lib.cohort_lane_keys(
+                ks[rounds.ROUND_KEY_LANES["bank"]], sel)
+            new_bank = self.bank.scatter(bank, sel, new_res_sel, lanes)
         return new_params, metrics, new_bank, delta_hat, new_chan
 
     def _step_impl(self, state: TrainState, data_x, data_y):
@@ -256,6 +260,17 @@ class Trainer:
         t = self.cfg.rounds if rounds is None else int(rounds)
         if self.bank.backend == "streamed":
             return self._run_streamed(state, data_x, data_y, t)
+        return self.run_program(t)(state, data_x, data_y)
+
+    def run_program(self, rounds: int):
+        """The jitted ``trainer_run(state, data_x, data_y)`` program that
+        ``run(rounds=rounds)`` calls under the resident bank, so that
+        ``trainer.run_program(T).lower(state, x, y).compile()`` gives its
+        compiled text ahead of time (as ``trainer.step.lower`` does)."""
+        if self.bank.backend == "streamed":
+            raise ValueError("the streamed bank runs a host loop of "
+                             "rounds, not one program")
+        t = int(rounds)
         fn = self._run_cache.get(t)
         if fn is None:
             # named, so compile logs and profiles can find the program
@@ -263,7 +278,7 @@ class Trainer:
                 return self._run_impl(s, x, y, t)
             fn = jax.jit(trainer_run)
             self._run_cache[t] = fn
-        return fn(state, data_x, data_y)
+        return fn
 
     def _run_impl(self, state: TrainState, data_x, data_y, t_rounds: int):
         def body(carry, xs):
@@ -275,10 +290,11 @@ class Trainer:
             ledger, metrics = self._spend(ledger, metrics)
             return (p2, bank2, delta_hat, ledger, chan2), metrics
 
-        keys = jax.random.split(state.key, t_rounds)
-        # absolute round counters, so chunked resume anneals the
-        # CompressionSchedule from where the last chunk stopped
-        ts = state.round + jnp.arange(t_rounds, dtype=jnp.int32)
+        with jax.named_scope("pfels.cohort"):
+            keys = jax.random.split(state.key, t_rounds)
+            # absolute round counters, so chunked resume anneals the
+            # CompressionSchedule from where the last chunk stopped
+            ts = state.round + jnp.arange(t_rounds, dtype=jnp.int32)
         (p_f, bank_f, delta_f, ledger_f, chan_f), metrics = jax.lax.scan(
             body, (state.params, state.bank, state.prev_delta,
                    state.ledger, state.chan), (keys, ts))
@@ -306,8 +322,9 @@ class Trainer:
                                       prev_delta, chan, sel, t,
                                       ledger.eps_sum)
                 ledger, metrics = self._spend(ledger, metrics)
-                lanes = bank_lib.cohort_lane_keys(
-                    ks[rounds.ROUND_KEY_LANES["bank"]], sel)
+                with jax.named_scope("pfels.bank"):
+                    lanes = bank_lib.cohort_lane_keys(
+                        ks[rounds.ROUND_KEY_LANES["bank"]], sel)
                 return (new_params, metrics, new_res_sel, lanes, delta_hat,
                         ledger, new_chan)
 
@@ -331,33 +348,48 @@ class Trainer:
                 f"cfg.num_clients, so a mismatched source silently "
                 f"truncates the population (and the Thm 2 r/n "
                 f"accounting)")
-        ks_all = jax.vmap(rounds.split_round_key)(round_keys)  # (T, 7, ·)
-        sels = jax.vmap(lambda ks: rounds.sample_cohort(
-            ks[rounds.ROUND_KEY_LANES["selection"]], n, r))(ks_all)
-        sels_np = np.asarray(sels)
+        # one host span per step, in the profiler's trace when it records:
+        # ``round`` is the call's round the step serves (the call's set-up
+        # counts to round 0, stacking the metrics to its last round),
+        # ``bytes`` what the step moves between host and device
+        span = jax.profiler.TraceAnnotation
+        with span("streamed.cohorts", round=0):
+            ks_all = jax.vmap(rounds.split_round_key)(round_keys)  # (T,7,·)
+            sels = jax.vmap(lambda ks: rounds.sample_cohort(
+                ks[rounds.ROUND_KEY_LANES["selection"]], n, r))(ks_all)
+            sels_np = np.asarray(sels)
         step_fn = self._cohort_step()
 
-        bank = self.bank.clone(state.bank)   # callers keep their state
+        with span("streamed.bank_clone", round=0):
+            bank = self.bank.clone(state.bank)   # callers keep their state
         params, prev_delta, ledger = state.params, state.prev_delta, \
             state.ledger
         chan = state.chan                    # device-resident model carry
         per_round = []
         prefetch = loader.prefetch_cohorts(source, sels_np)
-        for ti, (cx, cy) in enumerate(prefetch):
-            sel = sels_np[ti]
-            res_sel = self.bank.gather(bank, sel)
-            if res_sel is not None:
-                res_sel = jnp.asarray(res_sel)
-            params, metrics, new_res_sel, lanes, prev_delta, ledger, \
-                chan = step_fn(
-                    params, jnp.asarray(state.power_limits)[sel],
-                    cx, cy, ks_all[ti], jnp.asarray(sel), res_sel,
-                    prev_delta, ledger, chan,
-                    state.round + jnp.asarray(ti, jnp.int32))
-            bank = self.bank.scatter(bank, sel, new_res_sel, lanes)
+        for ti, sel in enumerate(sels_np):
+            with span("streamed.prefetch_wait", round=ti):
+                cx, cy = next(prefetch)
+            with span("streamed.gather", round=ti) as sp:
+                res_sel = self.bank.gather(bank, sel)
+                if res_sel is not None:
+                    res_sel = jnp.asarray(res_sel)
+                    sp.set_metadata(bytes=res_sel.nbytes)
+            with span("streamed.dispatch", round=ti):
+                params, metrics, new_res_sel, lanes, prev_delta, ledger, \
+                    chan = step_fn(
+                        params, jnp.asarray(state.power_limits)[sel],
+                        cx, cy, ks_all[ti], jnp.asarray(sel), res_sel,
+                        prev_delta, ledger, chan,
+                        state.round + jnp.asarray(ti, jnp.int32))
+            with span("streamed.scatter", round=ti, bytes=lanes.nbytes + (
+                    0 if new_res_sel is None else new_res_sel.nbytes)):
+                bank = self.bank.scatter(bank, sel, new_res_sel, lanes)
             per_round.append(metrics)
-        stacked = {k: np.stack([np.asarray(m[k]) for m in per_round])
-                   for k in per_round[0]}
+        prefetch.close()
+        with span("streamed.stack_metrics", round=len(sels_np) - 1):
+            stacked = {k: np.stack([np.asarray(m[k]) for m in per_round])
+                       for k in per_round[0]}
         return params, stacked, bank, prev_delta, ledger, chan
 
     def _run_streamed(self, state: TrainState, data_x, data_y, t: int):

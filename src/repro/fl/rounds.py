@@ -226,21 +226,24 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
         round counter ``t`` and the ledger's running spend anneal the
         live-slot column (ANDed into the support), the power limits, and
         the per-round ε ceiling — all traced, zero host round-trips."""
-        sup = compressors.as_support(
-            alg.select_support(cfg, d, k_coords, prev_delta, idx_key))
-        eps_t = None
-        if sched_on:
-            ka = compressors.schedules.k_active(sched, cfg, k_coords, t)
-            if ka is not None:
-                sup = compressors.and_active(sup, ka)
-            ps = compressors.schedules.power_scale(sched, cfg, t)
-            if ps is not None:
-                p_sel = p_sel * ps
-            eps_t = compressors.schedules.epsilon_round(sched, cfg, t,
-                                                        eps_spent)
-        k_used = compressors.support_size(sup)
-        beta = alg.design_beta(cfg, gains_design, p_sel, d, k_used,
-                               epsilon=eps_t, c1_scale=c1_scale)
+        with jax.named_scope("pfels.support"):
+            sup = compressors.as_support(
+                alg.select_support(cfg, d, k_coords, prev_delta, idx_key))
+            if sched_on:
+                ka = compressors.schedules.k_active(sched, cfg, k_coords, t)
+                if ka is not None:
+                    sup = compressors.and_active(sup, ka)
+        with jax.named_scope("pfels.beta"):
+            eps_t = None
+            if sched_on:
+                ps = compressors.schedules.power_scale(sched, cfg, t)
+                if ps is not None:
+                    p_sel = p_sel * ps
+                eps_t = compressors.schedules.epsilon_round(sched, cfg, t,
+                                                            eps_spent)
+            k_used = compressors.support_size(sup)
+            beta = alg.design_beta(cfg, gains_design, p_sel, d, k_used,
+                                   epsilon=eps_t, c1_scale=c1_scale)
         return sup, beta, k_used
 
     cohort_apply = None
@@ -253,37 +256,43 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
             # for scalar channels — bit-exact identity, DESIGN.md §12)
             # inside the manual region: sharding constraints must not
             # re-reference the cohort axes
-            with rules.exclude_axes(*_COHORT_AXES):
+            with rules.exclude_axes(*_COHORT_AXES), \
+                    jax.named_scope("pfels.local_train"):
                 flat_l, losses_l = client_updates(params, cx_l, cy_l, ck_l)
-            if ef_on:
-                flat_l = flat_l + res_l
+                if ef_on:
+                    flat_l = flat_l + res_l
             tx_l = flat_l
             if aircomp:
-                # same once-only clip-scale policy as the vmapped branch:
-                # error feedback / encode need the clipped updates anyway,
-                # so pre-apply the clip here, hand the aggregator clip=None,
-                # and ship the as-transmitted updates back sharded for the
-                # residual (compressors.sparsify of tx_l == what went on
-                # the air)
-                agg_updates, agg_clip = flat_l, cfg.transmit_clip
-                if pre_clip:
-                    scales_l = transmit_ref.clip_scales(flat_l,
-                                                        cfg.transmit_clip)
-                    agg_updates = flat_l * scales_l[:, None]
-                    agg_clip = None
-                if has_encode:
-                    agg_updates = comp.encode(cfg, agg_updates, qk_l)
-                tx_l = agg_updates
-                delta_hat, energy, _ = aggregation.aircomp_aggregate_sharded(
-                    agg_updates, idx, gains_l, beta, noise_key, d=d,
-                    sigma0=sigma0, r=r, axis_name=_COHORT_AXES,
-                    unbiased_rescale=cfg.unbiased_rescale,
-                    gains_est_local=(gest_l if cfg.channel.csi_error > 0
-                                     else None),
-                    clip=agg_clip,
-                    use_kernel=cfg.use_fused_kernel,
-                    tx_mask_local=(mask_l if has_mask else None),
-                    active=(act if dyn_active else None))
+                with jax.named_scope("pfels.aircomp"):
+                    # same once-only clip-scale policy as the vmapped
+                    # branch: error feedback / encode need the clipped
+                    # updates anyway, so pre-apply the clip here, hand the
+                    # aggregator clip=None, and ship the as-transmitted
+                    # updates back sharded for the residual
+                    # (compressors.sparsify of tx_l == what went on the
+                    # air)
+                    agg_updates, agg_clip = flat_l, cfg.transmit_clip
+                    if pre_clip:
+                        scales_l = transmit_ref.clip_scales(
+                            flat_l, cfg.transmit_clip)
+                        agg_updates = flat_l * scales_l[:, None]
+                        agg_clip = None
+                    if has_encode:
+                        agg_updates = comp.encode(cfg, agg_updates, qk_l)
+                    tx_l = agg_updates
+                    delta_hat, energy, _ = \
+                        aggregation.aircomp_aggregate_sharded(
+                            agg_updates, idx, gains_l, beta, noise_key,
+                            d=d, sigma0=sigma0, r=r,
+                            axis_name=_COHORT_AXES,
+                            unbiased_rescale=cfg.unbiased_rescale,
+                            gains_est_local=(
+                                gest_l if cfg.channel.csi_error > 0
+                                else None),
+                            clip=agg_clip,
+                            use_kernel=cfg.use_fused_kernel,
+                            tx_mask_local=(mask_l if has_mask else None),
+                            active=(act if dyn_active else None))
             else:
                 # dp_fedavg / fedavg aggregate on the gathered updates
                 # outside the manual region; only training is sharded
@@ -302,33 +311,40 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
     def cohort_core(params, p_sel, cx, cy, ks, res_sel=None,
                     prev_delta=None, chan_carry=None, sel=None,
                     t=None, eps_spent=None):
-        ck = jax.random.split(ks[ROUND_KEY_LANES["client_train"]], r)
-        # stochastic-rounding keys: fold_in-derived from the support lane
-        # (DESIGN.md §5 — the 7-lane round split stays pinned); unused
-        # (DCE'd) unless the compressor encodes
-        qk = jax.random.split(
-            jax.random.fold_in(ks[ROUND_KEY_LANES["support"]],
-                               compressors.QUANT_STREAM_TAG), r)
+        # each layer of the round runs under one flat ``pfels.*`` name
+        # scope, which the compiled ops carry as op_name metadata into the
+        # profiler's trace (PERF.md section 3 lists who reads each)
+        with jax.named_scope("pfels.local_train"):
+            ck = jax.random.split(ks[ROUND_KEY_LANES["client_train"]], r)
+        with jax.named_scope("pfels.aircomp"):
+            # stochastic-rounding keys: fold_in-derived from the support
+            # lane (DESIGN.md §5 — the 7-lane round split stays pinned);
+            # unused (DCE'd) unless the compressor encodes
+            qk = jax.random.split(
+                jax.random.fold_in(ks[ROUND_KEY_LANES["support"]],
+                                   compressors.QUANT_STREAM_TAG), r)
 
         # ---- channel realization for this round (DESIGN.md §11): the
         # registered model consumes the gains/csi lanes and evolves its
         # cross-round carry; imperfect CSI (beyond paper): clients
         # precompensate with noisy gain estimates while the MAC applies
         # the true gains
-        new_chan_carry, cr = chan_model.step(
-            chan_carry, cfg.channel, r, sel,
-            ks[ROUND_KEY_LANES["gains"]], ks[ROUND_KEY_LANES["csi"]])
-        if cr.tx_mask is not None and not has_mask:
-            # a silent discard here would let beta design / r_realized see
-            # the mask while aggregation ignores it — contradictory
-            # numerics; fail at trace time instead
-            raise ValueError(
-                f"channel model {chan_model.name!r} returned a tx_mask "
-                f"but its may_mask(cfg) hook says False — the mask "
-                f"plumbing is gated on may_mask (DESIGN.md §11)")
-        gains = cr.gains
-        gains_obs = channels.observed_gains(cr)
-        tx_mask = cr.tx_mask
+        with jax.named_scope("pfels.channel"):
+            new_chan_carry, cr = chan_model.step(
+                chan_carry, cfg.channel, r, sel,
+                ks[ROUND_KEY_LANES["gains"]], ks[ROUND_KEY_LANES["csi"]])
+            if cr.tx_mask is not None and not has_mask:
+                # a silent discard here would let beta design / r_realized
+                # see the mask while aggregation ignores it — contradictory
+                # numerics; fail at trace time instead
+                raise ValueError(
+                    f"channel model {chan_model.name!r} returned a tx_mask "
+                    f"but its may_mask(cfg) hook says False — the mask "
+                    f"plumbing is gated on may_mask (DESIGN.md §11)")
+            gains = cr.gains
+            gains_obs = channels.observed_gains(cr)
+            gains_design = channels.design_gains(cr)
+            tx_mask = cr.tx_mask
 
         sup = beta = None
         k_used = d
@@ -338,7 +354,7 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
             # precompensation the devices actually apply — with dropped
             # clients lifted out of the min (design_gains)
             sup, beta, k_used = support_and_beta(
-                channels.design_gains(cr), p_sel, prev_delta,
+                gains_design, p_sel, prev_delta,
                 ks[ROUND_KEY_LANES["support"]],
                 t, eps_spent)
 
@@ -350,6 +366,8 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
         agg_sharded = None
         tx_full = None    # the as-transmitted (clipped/encoded) updates
         if cohort_apply is not None:
+            # the body names its own local_train and aircomp scopes: a
+            # scope around the shard_map would nest them
             res_l = (res_sel if use_ef
                      else jnp.zeros((r, d), jnp.float32))
             gains_mat = (cr.gains_ant if cr.gains_ant is not None
@@ -371,88 +389,99 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
                 agg_sharded = (delta_sh, energy_sh)
                 tx_full = tx_sh
         else:
-            flat_updates, losses = client_updates(params, cx, cy, ck)
-            if use_ef:
-                flat_updates = flat_updates + res_sel
+            with jax.named_scope("pfels.local_train"):
+                flat_updates, losses = client_updates(params, cx, cy, ck)
+                if use_ef:
+                    flat_updates = flat_updates + res_sel
 
-        metrics: Dict[str, jnp.ndarray] = {
-            "train_loss": jnp.mean(losses),
-            "update_norm": jnp.mean(
-                jnp.linalg.norm(flat_updates, axis=1)),
-            # == r unless the channel model masks transmissions (dropout):
-            # the realized transmitter count of the DESIGN.md §11 contract
-            "r_realized": channels.realized_cohort_size(cr, r),
-        }
+        with jax.named_scope("pfels.local_train"):
+            metrics: Dict[str, jnp.ndarray] = {
+                "train_loss": jnp.mean(losses),
+                "update_norm": jnp.mean(
+                    jnp.linalg.norm(flat_updates, axis=1)),
+                # == r unless the channel model masks transmissions
+                # (dropout): the realized transmitter count of the
+                # DESIGN.md §11 contract
+                "r_realized": channels.realized_cohort_size(cr, r),
+            }
 
-        if aircomp:
-            if agg_sharded is not None:
-                delta_hat, energy = agg_sharded
-            else:
-                # error feedback needs the clip scales for the residual
-                # anyway (and encode must see the clipped update), so
-                # compute them ONCE here and hand the aggregator
-                # pre-clipped updates (clip=None) instead of paying a second
-                # full (r, d) norm sweep inside the fused kernel's
-                # client_sumsq pass
-                agg_updates, agg_clip = flat_updates, cfg.transmit_clip
-                if pre_clip:
-                    agg_updates = flat_updates * transmit_ref.clip_scales(
-                        flat_updates, cfg.transmit_clip)[:, None]
-                    agg_clip = None
-                if has_encode:
-                    agg_updates = comp.encode(cfg, agg_updates, qk)
-                tx_full = agg_updates
-                agg_kw = dict(
-                    d=d, sigma0=sigma0, r=r,
-                    unbiased_rescale=cfg.unbiased_rescale,
-                    gains_est=(cr.gains_obs if cfg.channel.csi_error > 0
-                               else None),
-                    clip=agg_clip, tx_mask=tx_mask,
-                    active=sup.active)
-                if cfg.use_fused_kernel:
-                    # the whole scenario matrix rides the kernel in-tile:
-                    # tx_mask as a coefficient column, per-antenna gains
-                    # through the MRC combine (DESIGN.md §12)
-                    delta_hat, energy, y_agg = \
-                        aggregation.aircomp_aggregate_fused(
-                            agg_updates, sup.idx, gains, beta,
-                            ks[ROUND_KEY_LANES["channel_noise"]],
-                            gains_ant=cr.gains_ant, **agg_kw)
+        with jax.named_scope("pfels.aircomp"):
+            if aircomp:
+                if agg_sharded is not None:
+                    delta_hat, energy = agg_sharded
                 else:
-                    delta_hat, energy, y_agg = aggregation.aircomp_aggregate(
-                        agg_updates, sup.idx, gains, beta,
-                        ks[ROUND_KEY_LANES["channel_noise"]], **agg_kw)
-                if comp is not None and comp.decode is not None:
-                    # custom server-side reconstruction: the hook replaces
-                    # the default A^T unprojection of the k-subcarrier
-                    # payload; the 1/(r beta) unscale and the beyond-paper
-                    # d/k unbiasing stay the round body's job
-                    delta_hat = comp.decode(cfg, y_agg, sup, d) / (
-                        aggregation.realized_r(tx_mask, r) * beta)
-                    if cfg.unbiased_rescale:
-                        delta_hat = delta_hat * (d / k_coords)
-            metrics.update(beta=beta, energy=energy,
-                           subcarriers=jnp.asarray(k_used))
-        else:   # digital server-side aggregation (registry hook)
-            # a dropped client uploads nothing in the digital schemes too
-            agg_in = (flat_updates * tx_mask[:, None]
-                      if tx_mask is not None else flat_updates)
-            delta_hat = alg.server_aggregate(
-                cfg, agg_in, ks[ROUND_KEY_LANES["channel_noise"]],
-                d=d, r=r)
-            if tx_mask is not None:
-                # same realized-r contract as the AirComp paths: the hook
-                # averaged over the nominal r, so rescale to the mean of
-                # the updates actually RECEIVED (for dp_fedavg this also
-                # scales its noise by r/r_eff >= 1 — conservative). An
-                # all-dropped round received NOTHING: apply no update
-                # rather than an r-fold-amplified pure-noise step
-                delta_hat = jnp.where(
-                    jnp.sum(tx_mask) > 0,
-                    delta_hat * (r / aggregation.realized_r(tx_mask, r)),
-                    jnp.zeros_like(delta_hat))
-            metrics.update(beta=jnp.asarray(0.0), energy=jnp.asarray(0.0),
-                           subcarriers=jnp.asarray(d))
+                    # error feedback needs the clip scales for the
+                    # residual anyway (and encode must see the clipped
+                    # update), so compute them ONCE here and hand the
+                    # aggregator pre-clipped updates (clip=None) instead
+                    # of paying a second full (r, d) norm sweep inside the
+                    # fused kernel's client_sumsq pass
+                    agg_updates, agg_clip = flat_updates, cfg.transmit_clip
+                    if pre_clip:
+                        agg_updates = flat_updates * \
+                            transmit_ref.clip_scales(
+                                flat_updates, cfg.transmit_clip)[:, None]
+                        agg_clip = None
+                    if has_encode:
+                        agg_updates = comp.encode(cfg, agg_updates, qk)
+                    tx_full = agg_updates
+                    agg_kw = dict(
+                        d=d, sigma0=sigma0, r=r,
+                        unbiased_rescale=cfg.unbiased_rescale,
+                        gains_est=(cr.gains_obs if cfg.channel.csi_error > 0
+                                   else None),
+                        clip=agg_clip, tx_mask=tx_mask,
+                        active=sup.active)
+                    if cfg.use_fused_kernel:
+                        # the whole scenario matrix rides the kernel
+                        # in-tile: tx_mask as a coefficient column,
+                        # per-antenna gains through the MRC combine
+                        # (DESIGN.md §12)
+                        delta_hat, energy, y_agg = \
+                            aggregation.aircomp_aggregate_fused(
+                                agg_updates, sup.idx, gains, beta,
+                                ks[ROUND_KEY_LANES["channel_noise"]],
+                                gains_ant=cr.gains_ant, **agg_kw)
+                    else:
+                        delta_hat, energy, y_agg = \
+                            aggregation.aircomp_aggregate(
+                                agg_updates, sup.idx, gains, beta,
+                                ks[ROUND_KEY_LANES["channel_noise"]],
+                                **agg_kw)
+                    if comp is not None and comp.decode is not None:
+                        # custom server-side reconstruction: the hook
+                        # replaces the default A^T unprojection of the
+                        # k-subcarrier payload; the 1/(r beta) unscale and
+                        # the beyond-paper d/k unbiasing stay the round
+                        # body's job
+                        delta_hat = comp.decode(cfg, y_agg, sup, d) / (
+                            aggregation.realized_r(tx_mask, r) * beta)
+                        if cfg.unbiased_rescale:
+                            delta_hat = delta_hat * (d / k_coords)
+                metrics.update(beta=beta, energy=energy,
+                               subcarriers=jnp.asarray(k_used))
+            else:   # digital server-side aggregation (registry hook)
+                # a dropped client uploads nothing in the digital
+                # schemes too
+                agg_in = (flat_updates * tx_mask[:, None]
+                          if tx_mask is not None else flat_updates)
+                delta_hat = alg.server_aggregate(
+                    cfg, agg_in, ks[ROUND_KEY_LANES["channel_noise"]],
+                    d=d, r=r)
+                if tx_mask is not None:
+                    # same realized-r contract as the AirComp paths: the
+                    # hook averaged over the nominal r, so rescale to the
+                    # mean of the updates actually RECEIVED (for dp_fedavg
+                    # this also scales its noise by r/r_eff >= 1 —
+                    # conservative). An all-dropped round received
+                    # NOTHING: apply no update rather than an
+                    # r-fold-amplified pure-noise step
+                    delta_hat = jnp.where(
+                        jnp.sum(tx_mask) > 0,
+                        delta_hat * (r / aggregation.realized_r(tx_mask, r)),
+                        jnp.zeros_like(delta_hat))
+                metrics.update(beta=jnp.asarray(0.0), energy=jnp.asarray(0.0),
+                               subcarriers=jnp.asarray(d))
 
         # ---- error-feedback memory update: e_i <- u_i - A^T A q(s_i u_i)
         # — the residual is the raw update minus what was ACTUALLY sent
@@ -467,24 +496,27 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
         # owns the scatter into the (n, d) bank.
         new_res_sel = res_sel
         if use_ef:
-            base = tx_full if (aircomp and tx_full is not None) \
-                else flat_updates
-            if alg.sparsifies_transmit:
-                transmitted = jax.vmap(
-                    lambda u: compressors.sparsify(u, sup, d))(base)
-            else:
-                transmitted = base
-            if tx_mask is not None:
-                # a dropped client transmitted NOTHING: its whole update
-                # stays in the residual memory for its next participation
-                transmitted = transmitted * tx_mask[:, None]
-            new_res_sel = flat_updates - transmitted
+            with jax.named_scope("pfels.bank"):
+                base = tx_full if (aircomp and tx_full is not None) \
+                    else flat_updates
+                if alg.sparsifies_transmit:
+                    transmitted = jax.vmap(
+                        lambda u: compressors.sparsify(u, sup, d))(base)
+                else:
+                    transmitted = base
+                if tx_mask is not None:
+                    # a dropped client transmitted NOTHING: its whole
+                    # update stays in the residual memory for its next
+                    # participation
+                    transmitted = transmitted * tx_mask[:, None]
+                new_res_sel = flat_updates - transmitted
 
         # ---- server update (line 16)
-        flat_params, _ = ravel_pytree(params)
-        new_flat = flat_params + delta_hat
-        return unravel(new_flat), metrics, new_res_sel, delta_hat, \
-            new_chan_carry
+        with jax.named_scope("pfels.server"):
+            flat_params, _ = ravel_pytree(params)
+            new_flat = flat_params + delta_hat
+            new_params = unravel(new_flat)
+        return new_params, metrics, new_res_sel, delta_hat, new_chan_carry
 
     return cohort_core
 
