@@ -32,13 +32,23 @@ def dense_noise_and_mask(idx: jnp.ndarray, noise_key, sigma0: float,
     optional (k,) 0/1 live-slot column (DESIGN.md §13): the draw keeps
     its fixed k shape (the PRNG stream is schedule-independent), then
     deactivated slots are zeroed out of BOTH columns — no signal, no
-    measured noise on an unallocated subcarrier."""
+    measured noise on an unallocated subcarrier.
+
+    Without ``active`` both columns come from ONE scatter of the support
+    (DESIGN.md §4): the noise goes into a NaN-filled column, so a NaN
+    marks every coordinate off omega (the noise is finite, so none on
+    omega). The support is a subset of [d], hence unique and in bounds,
+    and the scatter is told so. With ``active`` an inactive slot needs
+    mask 0 and the signed zero ``noise * 0``, which the sentinel cannot
+    carry, so that branch scatters the two columns separately."""
     noise = sigma0 * jax.random.normal(noise_key, (idx.shape[0],))
     if active is None:
-        mask = jnp.zeros((d,), jnp.float32).at[idx].set(1.0)
-    else:
-        noise = noise * active
-        mask = jnp.zeros((d,), jnp.float32).at[idx].set(active)
+        col = jnp.full((d,), jnp.nan, jnp.float32).at[idx].set(
+            noise, unique_indices=True, mode="promise_in_bounds")
+        off = jnp.isnan(col)
+        return (~off).astype(jnp.float32), jnp.where(off, 0.0, col)
+    noise = noise * active
+    mask = jnp.zeros((d,), jnp.float32).at[idx].set(active)
     z_dense = jnp.zeros((d,), jnp.float32).at[idx].set(noise)
     return mask, z_dense
 

@@ -67,6 +67,43 @@ def test_noise_draw_bit_identical():
         assert bool(jnp.all(y0 == y1))
 
 
+def _two_scatter_columns(idx, noise_key, sigma0, d, active=None):
+    """The dense columns as two scatters into zero columns: the oracle
+    for the one-scatter ``dense_noise_and_mask``."""
+    noise = sigma0 * jax.random.normal(noise_key, (idx.shape[0],))
+    ones = jnp.ones(idx.shape, jnp.float32)
+    if active is not None:
+        noise, ones = noise * active, active
+    mask = jnp.zeros((d,), jnp.float32).at[idx].set(ones)
+    z_dense = jnp.zeros((d,), jnp.float32).at[idx].set(noise)
+    return mask, z_dense
+
+
+@pytest.mark.parametrize("sigma0", [0.7, 0.0])     # 0.0: signed zeros
+@pytest.mark.parametrize("with_active", [False, True],
+                         ids=["active_none", "active_col"])
+@pytest.mark.parametrize("d,k", [(301, 90), (301, 1), (301, 301),
+                                 (1024, 307)])
+def test_dense_columns_match_two_scatters(d, k, with_active, sigma0):
+    key = jax.random.PRNGKey(11)
+    idx = randk.sample_indices(jax.random.fold_in(key, 1), d, k)
+    nk = jax.random.fold_in(key, 2)
+    active = (jax.random.bernoulli(jax.random.fold_in(key, 3), 0.5, (k,))
+              .astype(jnp.float32) if with_active else None)
+    # both compiled, as in the round (eager and compiled noise draws may
+    # differ in the last bit)
+    got, want = (jax.jit(f, static_argnums=(2, 3))(idx, nk, sigma0, d,
+                                                    active)
+                 for f in (tref.dense_noise_and_mask, _two_scatter_columns))
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype == np.float32
+        assert np.array_equal(g, w)
+        assert np.array_equal(np.signbit(g), np.signbit(w))
+    if sigma0 == 0.0 and k > 1:     # negative zeros on omega are tested
+        assert np.signbit(np.asarray(want[1])).any()
+
+
 @pytest.mark.parametrize("use_kernel", [True, False],
                          ids=["pallas", "jax_ref"])
 def test_fused_clip_matches_preclipped_unfused(use_kernel):

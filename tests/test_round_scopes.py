@@ -38,13 +38,18 @@ def problem():
 
 
 @pytest.fixture(scope="module")
-def op_names(problem):
-    """The op_name of every instruction of the compiled 2-round run."""
+def compiled_text(problem):
+    """The compiled 2-round run (fused kernel, rand_k support)."""
     params, x, y, loss_fn = problem
     trainer = Trainer(PFELSConfig(**BASE), loss_fn, params)
     state = trainer.init(jax.random.PRNGKey(1))
-    text = trainer.run_program(2).lower(state, x, y).compile().as_text()
-    return re.findall(r'op_name="([^"]*)"', text)
+    return trainer.run_program(2).lower(state, x, y).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def op_names(compiled_text):
+    """The op_name of every instruction of the compiled 2-round run."""
+    return re.findall(r'op_name="([^"]*)"', compiled_text)
 
 
 def _scopes(op_name):
@@ -68,6 +73,15 @@ def test_local_training_backward_is_named(op_names):
                 if o.startswith("jit(trainer_run)/") and "transpose(" in o]
     assert backward
     assert all(_scopes(o) == {"pfels.local_train"} for o in backward)
+
+
+def test_aggregation_scatters_the_support_once(compiled_text):
+    """The dense mask and noise columns come from one scatter of the
+    support (DESIGN.md section 4); on a TPU each scatter adds a sort."""
+    scatters = [line for line in compiled_text.splitlines()
+                if re.search(r"= \S+ scatter\(", line)
+                and "pfels.aircomp" in line]
+    assert len(scatters) == 1, scatters
 
 
 def test_run_program_is_the_resident_run(problem):
