@@ -167,6 +167,27 @@ class CNNConfig:
 
 
 @dataclass(frozen=True)
+class ViTConfig:
+    """Vision transformer image classifier (:mod:`repro.models.vit`).
+
+    The resident images are ``in_channels x image_size x image_size``; the
+    forward pass upsamples them to ``resize_to`` pixels before cutting
+    ``patch_size`` patches, so there are ``(resize_to / patch_size)^2 + 1``
+    tokens with the class token."""
+    name: str
+    in_channels: int = 3
+    image_size: int = 32
+    resize_to: int = 224
+    patch_size: int = 16
+    hidden: int = 384
+    heads: int = 6
+    mlp_dim: int = 1536
+    depth: int = 12
+    num_classes: int = 10
+    source: str = ""
+
+
+@dataclass(frozen=True)
 class ChannelConfig:
     """Wireless channel scenario (paper §8.1 + the DESIGN.md §11 registry).
 

@@ -130,6 +130,106 @@ def _cohort_shards(cfg: PFELSConfig, mesh: Optional[Mesh]) -> int:
     return n
 
 
+def local_train_slice(cfg: PFELSConfig, loss_fn: Callable) -> Callable:
+    """Local training (Alg. 2 lines 5-11) vmapped over a slice of clients:
+    ``(params, cx, cy, ck) -> ((n, d) flat updates, (n,) losses)``."""
+    train = functools.partial(
+        local_train, loss_fn=loss_fn, steps=cfg.local_steps,
+        lr=cfg.local_lr, clip=cfg.clip, momentum=cfg.momentum)
+
+    def train_slice(params, cx, cy, ck):
+        new_params, losses = jax.vmap(
+            lambda x, y, k: train(params, x, y, k))(cx, cy, ck)
+        updates = jax.vmap(lambda np_: model_update(params, np_))(new_params)
+        flat = jax.vmap(lambda u: ravel_pytree(u)[0])(updates)
+        return flat, losses
+    return train_slice
+
+
+# Room kept beside local training's own peak, in (n, d) float32 arrays of
+# updates: compiled for a v5e at r = 32, the whole round's peak exceeds
+# local training's by 0.6 of one for ViT-S/16 and 0.3 for ResNet-18, and
+# the aggregation pads the updates into a second one. Two leave ViT-S/16
+# at 224 px chunks of 2 clients (a compiled round peak of 9.6 GiB; 14.4
+# GiB at 4) and the CNNs their whole cohort.
+ROUND_UPDATE_COPIES = 2
+
+
+def local_train_footprint(train_slice: Callable, params, cx, cy, ck,
+                          chunk: int) -> Optional[int]:
+    """Device bytes that training the n clients of ``cx`` ``chunk`` at a
+    time asks of the round: the compiled peak of ``train_slice`` on one
+    chunk, the other chunks' flat updates, and ``ROUND_UPDATE_COPIES``
+    more copies of all n updates. None where the compiler finds that one
+    chunk does not fit the device. The arguments are
+    ``jax.ShapeDtypeStruct``s of the whole slice; a ``sharding`` on them
+    selects the device compiled for."""
+    def part(s):
+        return jax.ShapeDtypeStruct((chunk,) + s.shape[1:], s.dtype,
+                                    sharding=s.sharding)
+    try:
+        compiled = jax.jit(train_slice).lower(
+            params, part(cx), part(cy), part(ck)).compile()
+    except jax.errors.JaxRuntimeError as e:
+        if "RESOURCE_EXHAUSTED" in str(e):
+            return None
+        raise
+    mem = compiled.memory_analysis()
+    n, per_client = cx.shape[0], mem.output_size_in_bytes // chunk
+    return (mem.peak_memory_in_bytes
+            + (n - chunk + ROUND_UPDATE_COPIES * n) * per_client)
+
+
+def client_chunk(train_slice: Callable, params, cx, cy, ck,
+                 memory_stats) -> int:
+    """How many of the n clients of ``cx`` train at once: the largest
+    divisor of n whose :func:`local_train_footprint` fits the device
+    memory not in use, ``bytes_limit - bytes_in_use`` of the allocator's
+    ``memory_stats`` (1 where none fits); all n where the backend keeps
+    no statistics. The whole cohort is tried first, so a cohort that fits
+    costs one compile; otherwise divisors are tried upwards, each compile
+    succeeding but the last, until one does not fit."""
+    n = cx.shape[0]
+    if not memory_stats or "bytes_limit" not in memory_stats:
+        return n
+    free = memory_stats["bytes_limit"] - memory_stats.get("bytes_in_use", 0)
+
+    def fits(chunk):
+        need = local_train_footprint(train_slice, params, cx, cy, ck, chunk)
+        return need is not None and need <= free
+    if fits(n):
+        return n
+    chunk = 1
+    for c in range(2, n):
+        if n % c == 0:
+            if not fits(c):
+                break
+            chunk = c
+    return chunk
+
+
+def train_in_chunks(train_slice: Callable, params, cx, cy, ck,
+                    chunk: int):
+    """``train_slice`` over the n clients ``chunk`` at a time: vmapped
+    within a chunk, ``lax.map`` over the chunks; ``chunk == n`` is
+    ``train_slice`` itself. Each client keeps its own key, so every chunk
+    size gives the same updates, to float rounding."""
+    n = cx.shape[0]
+    if chunk == n:
+        return train_slice(params, cx, cy, ck)
+    flat, losses = jax.lax.map(
+        lambda a: train_slice(params, *a),
+        jax.tree.map(lambda a: a.reshape((n // chunk, chunk) + a.shape[1:]),
+                     (cx, cy, ck)))
+    return flat.reshape(n, -1), losses.reshape(n)
+
+
+def device_memory():
+    """The first local device's allocator statistics, or None where the
+    backend keeps none (the CPU)."""
+    return jax.local_devices()[0].memory_stats()
+
+
 def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
                        unravel: Callable, mesh: Optional[Mesh] = None):
     """The raw (un-jitted) round body on COHORT slices, uniform across
@@ -194,18 +294,18 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
             f"the sharded-cohort path does not route yet; use "
             f"client_sharding='none' (DESIGN.md §13)")
 
-    train = functools.partial(
-        local_train, loss_fn=loss_fn, steps=cfg.local_steps,
-        lr=cfg.local_lr, clip=cfg.clip, momentum=cfg.momentum)
+    train_slice = local_train_slice(cfg, loss_fn)
 
     def client_updates(params, cx, cy, ck):
-        """Local training (Alg. 2 lines 5-11) vmapped over any client
-        slice -> ((r_slice, d) flat updates, (r_slice,) losses)."""
-        new_params, losses = jax.vmap(
-            lambda x, y, k: train(params, x, y, k))(cx, cy, ck)
-        updates = jax.vmap(lambda np_: model_update(params, np_))(new_params)
-        flat = jax.vmap(lambda u: ravel_pytree(u)[0])(updates)
-        return flat, losses
+        """Local training (Alg. 2 lines 5-11) over any client slice ->
+        ((r_slice, d) flat updates, (r_slice,) losses), in the largest
+        chunk of clients whose compiled footprint fits the device's free
+        memory (all of them, vmapped, where it fits or the backend keeps
+        no statistics)."""
+        specs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                             (params, cx, cy, ck))
+        chunk = client_chunk(train_slice, *specs, device_memory())
+        return train_in_chunks(train_slice, params, cx, cy, ck, chunk)
 
     def support_and_beta(gains_design, p_sel, prev_delta, idx_key,
                          t=None, eps_spent=None):

@@ -12,6 +12,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -100,3 +101,38 @@ def test_trainer_step_compiles_for_v5e(one_chip, no_compile_cache,
     hlo = trainer.step.lower(state, x, y).compile().as_text()
     # both passes: the clip's sum of squares and the fused combine
     assert hlo.count("tpu_custom_call") >= 2
+
+
+# the allocator's limit on one v5e chip (16 GiB of HBM less what the
+# runtime keeps), as the TPU compiler states it: 15.75 GiB
+V5E_BYTES_LIMIT = 16_911_433_728
+
+
+@pytest.mark.parametrize("model,clients,samples", [
+    ("PAPER_VGG11_CIFAR10", 1000, 50), ("PAPER_RESNET18_FEMNIST", 3550, 226)])
+def test_cnn_cohorts_train_at_once_on_one_v5e(one_chip, no_compile_cache,
+                                             model, clients, samples):
+    """The paper's CNNs at their published widths keep the vmapped
+    cohort (chunk = r = 32) on a 16 GB chip that holds the cell's resident
+    data and four parameter-sized arrays of state."""
+    from repro.configs import PFELSConfig, paper_models
+    from repro.fl import rounds
+    from repro.models import cnn
+
+    mc = getattr(paper_models, model)
+    cfg = PFELSConfig(num_clients=clients, clients_per_round=32,
+                      local_steps=5, momentum=0.9)
+    params = jax.eval_shape(lambda k: cnn.init_cnn(k, mc),
+                            jax.random.PRNGKey(0))
+    d = sum(a.size for a in jax.tree.leaves(params))
+    image = (mc.in_channels, mc.image_size, mc.image_size)
+    in_use = 4 * (clients * samples * (1 + int(np.prod(image))) + 4 * d)
+    train_slice = rounds.local_train_slice(
+        cfg, lambda p, b: cnn.cnn_loss(p, mc, b))
+    chunk = rounds.client_chunk(
+        train_slice, jax.tree.map(lambda a: _spec(a.shape, one_chip), params),
+        _spec((32, samples) + image, one_chip),
+        _spec((32, samples), one_chip, jnp.int32),
+        _spec((32, 2), one_chip, jnp.uint32),
+        {"bytes_limit": V5E_BYTES_LIMIT, "bytes_in_use": in_use})
+    assert chunk == 32
