@@ -7,5 +7,7 @@
 #   clip_norm        — fused two-pass l2 clip (Assumption 1)
 #   ssd_scan         — Mamba2 SSD chunk scan (ssm/hybrid archs)
 #   flash_attn       — flash-attention forward (prefill hot-spot)
+#   vit_attention    — ViT self-attention forward and backward, the (t, t)
+#                      scores kept in VMEM (models/vit.py local training)
 # Each: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper,
 # interpret=True on CPU), ref.py (pure-jnp oracle).

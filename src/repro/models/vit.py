@@ -11,16 +11,18 @@ Same signatures as :mod:`repro.models.cnn`: ``init_vit(key, cfg)`` and
 ``vit_loss(params, cfg, batch) -> (loss, aux)``. Each part of the forward
 pass runs under one flat name scope (``vit.patch_embed``,
 ``vit.attention``, ``vit.mlp``, ``vit.head``), which the compiled ops
-carry as op_name metadata into the profiler's trace.
+carry as op_name metadata into the profiler's trace. The softmax-attention
+core of each block, forward and backward, is one Pallas kernel
+(:mod:`repro.kernels.vit_attention`), so its (t, t) scores never leave the
+chip's VMEM.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ViTConfig
+from repro.kernels.vit_attention.ops import self_attention
 from repro.models.layers import dense_apply, dense_init, norm_apply, norm_init
 
 
@@ -75,13 +77,8 @@ def _patches(images, cfg: ViTConfig):
 
 
 def _attention(p, x, heads):
-    b, t, h = x.shape
-    dh = h // heads
     qkv = dense_apply(p["qkv"], norm_apply(p["ln1"], x, "layernorm"))
-    q, k, v = jnp.split(qkv.reshape(b, t, 3, heads, dh), 3, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q[:, :, 0], k[:, :, 0]) / math.sqrt(dh)
-    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v[:, :, 0])
-    return x + dense_apply(p["proj"], o.reshape(b, t, h))
+    return x + dense_apply(p["proj"], self_attention(qkv, heads))
 
 
 def _mlp(p, x):

@@ -9,6 +9,7 @@ import, so every test-runner worker collects the same tests and only the
 worker that runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +137,71 @@ def test_cnn_cohorts_train_at_once_on_one_v5e(one_chip, no_compile_cache,
         _spec((32, 2), one_chip, jnp.uint32),
         {"bytes_limit": V5E_BYTES_LIMIT, "bytes_in_use": in_use})
     assert chunk == 32
+
+
+# ------------------------------------------------------------ ViT attention
+
+def _kernels(hlo):
+    """The compiled Pallas kernels of an HLO text, by the name of the jitted
+    entry point that called them: ``.../jit(<name>)/pallas_call``, or
+    ``vmap(jit(<name>))/pallas_call`` where it was vmapped."""
+    names = []
+    for line in hlo.splitlines():
+        if '"tpu_custom_call"' in line:
+            op = re.search(r'op_name="[^"]*jit\((\w+)\)\)*/pallas_call"',
+                           line)
+            names.append(op.group(1) if op else None)
+    return names
+
+
+def test_vit_attention_compiles_for_v5e(one_chip, no_compile_cache):
+    """Forward and backward kernels at ViT-S/16 widths (197 tokens, 6 heads
+    of 64), vmapped over a chunk of 2 clients of B = 50, with the matmul
+    inputs in bfloat16: Mosaic takes them and their blocks fit VMEM."""
+    from repro.kernels.vit_attention.kernel import (vit_attention_bwd,
+                                                    vit_attention_fwd)
+
+    kw = dict(heads=6, block=2, interpret=False, mxu_dtype=jnp.bfloat16)
+
+    def fwd_bwd(qkv, do):
+        o, lse = jax.vmap(lambda x: vit_attention_fwd(x, **kw))(qkv)
+        return o, jax.vmap(lambda *a: vit_attention_bwd(*a, **kw))(
+            qkv, o, lse, do)
+
+    compiled = jax.jit(fwd_bwd).lower(
+        _spec((2, 50, 197, 1152), one_chip),
+        _spec((2, 50, 197, 384), one_chip)).compile()
+    assert _kernels(compiled.as_text()) == ["vit_attention_fwd",
+                                            "vit_attention_bwd"]
+    o, dqkv = compiled.out_info
+    assert (o.shape, dqkv.shape) == ((2, 50, 197, 384), (2, 50, 197, 1152))
+
+
+def test_vit_trainer_step_holds_the_kernels_for_v5e(one_chip,
+                                                   no_compile_cache,
+                                                   monkeypatch):
+    """A small ViT round compiled for the chip holds the attention kernel
+    forward and backward in every block and both transmit kernels, each
+    found by its name, and no float32 (t, t) scores or probabilities."""
+    from repro.configs import PFELSConfig
+    from repro.configs.paper_models import BENCH_VIT_TINY as mc
+    from repro.fl import Trainer
+    from repro.models import vit
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = vit.init_vit(jax.random.PRNGKey(0), mc)
+    cfg = PFELSConfig(num_clients=20, clients_per_round=4, local_steps=2,
+                      rounds=1, transmit_clip=0.5)
+    trainer = Trainer(cfg, lambda p, b: vit.vit_loss(p, mc, b), params)
+    put = lambda s: _spec(s.shape, one_chip, s.dtype)
+    state = jax.tree.map(put, jax.eval_shape(trainer.init,
+                                             jax.random.PRNGKey(0)))
+    x = _spec((20, 50, 3, 32, 32), one_chip)
+    y = _spec((20, 50), one_chip, jnp.int32)
+    hlo = trainer.step.lower(state, x, y).compile().as_text()
+    kernels = _kernels(hlo)
+    assert kernels.count("vit_attention_fwd") == mc.depth
+    assert kernels.count("vit_attention_bwd") == mc.depth
+    assert {"client_sumsq", "fused_combine"} <= set(kernels)
+    t = vit.num_tokens(mc)
+    assert not re.search(rf"f32\[(\d+,)*{t},{t}\]", hlo)

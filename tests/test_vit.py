@@ -128,6 +128,21 @@ def test_chunk_choice_follows_the_free_memory(cohort):
     assert rounds.client_chunk(train_slice, *specs, None) == R
 
 
+def test_chunk_choice_takes_the_smallest_chunk_that_fits(cohort):
+    """A cohort of 8 on a device that holds chunks of 2 and of 4 but not
+    the whole cohort trains 2 clients at a time: past the cohort, a larger
+    chunk buys memory, not throughput."""
+    train_slice, params, cx, cy, ck = cohort
+    eight = _specs(tuple(jnp.concatenate([a, a]) for a in (cx, cy, ck)))
+    specs = (_specs(params),) + eight
+    need = {c: rounds.local_train_footprint(train_slice, *specs, c)
+            for c in (2, 4, 2 * R)}
+    assert need[2] < need[4] < need[2 * R]
+    assert rounds.client_chunk(train_slice, *specs,
+                               {"bytes_limit": need[4],
+                                "bytes_in_use": 0}) == 2
+
+
 # ------------------------------------------------------------ round
 
 def _reference_harness():
