@@ -83,66 +83,47 @@ def bench_pfels_transmit(key, rows, *, r=16, d=128 * 512):
 def _fl_problem(cfg):
     """One shared FL benchmark problem (BENCH_MLP on synthetic federated
     data) so every round-driver row measures the same thing."""
-    import warnings
-
     from jax.flatten_util import ravel_pytree
 
     from repro.configs.paper_models import BENCH_MLP
     from repro.data import make_federated_classification
-    from repro.fl import setup
     from repro.models import cnn
 
     key = jax.random.PRNGKey(0)
     params = cnn.init_cnn(key, BENCH_MLP)
-    flat, unravel = ravel_pytree(params)
-    d = flat.shape[0]
+    d = ravel_pytree(params)[0].shape[0]
     x, y, _, _ = make_federated_classification(
         key, n_clients=30, per_client=30, num_classes=10,
         image_shape=(1, 8, 8))
     loss_fn = lambda p, b: cnn.cnn_loss(p, BENCH_MLP, b)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        st = setup(jax.random.PRNGKey(1), params, cfg, d)
-    return params, d, unravel, (x, y), loss_fn, st
+    return params, d, (x, y), loss_fn
 
 
 def bench_round_drivers(rows, *, t_rounds=8):
-    """T rounds, three drivers: python loop over the jitted legacy
-    round_fn (one dispatch per round), the legacy lax.scan driver, and
-    Trainer.run — the trainer_run-vs-legacy_scan pair demonstrates the new
-    API wrapper adds no dispatch overhead over the raw scan."""
-    import warnings
-
+    """T rounds, two drivers: a python loop over the jitted
+    ``Trainer.step`` (one dispatch per round), fed the run's own round
+    keys, and ``Trainer.run`` (one ``lax.scan`` program)."""
     from repro.configs import PFELSConfig
-    from repro.fl import Trainer, make_round_fn, make_training_fn
+    from repro.fl import Trainer
     from repro.fl.api import replace
 
     cfg = PFELSConfig(num_clients=30, clients_per_round=8, local_steps=3,
                       rounds=t_rounds)
-    params, d, unravel, (x, y), loss_fn, st = _fl_problem(cfg)
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        fn = make_round_fn(cfg, loss_fn, d, unravel)
-        tf = make_training_fn(cfg, loss_fn, d, unravel, rounds=t_rounds)
-    keys = jax.random.split(jax.random.PRNGKey(2), t_rounds)
-
-    def loop():
-        p = params
-        for t in range(t_rounds):
-            p, m = fn(p, st.power_limits, x, y, keys[t])
-        return p
-
-    us = _time(lambda: jax.tree.leaves(loop())[0], reps=3)
-    rows.append(("rounds_python_loop", us, f"T={t_rounds},d={d}"))
-
-    us = _time(lambda: tf(params, st.power_limits, x, y,
-                          jax.random.PRNGKey(2))[0], reps=3)
-    rows.append(("rounds_legacy_scan", us, f"T={t_rounds},d={d}"))
-
+    params, d, (x, y), loss_fn = _fl_problem(cfg)
     trainer = Trainer(cfg, loss_fn, params)
     state = replace(trainer.init(jax.random.PRNGKey(1)),
                     key=jax.random.PRNGKey(2))
+    keys = jax.random.split(state.key, t_rounds)
+
+    def loop():
+        s = state
+        for k in keys:
+            s, _ = trainer.step(replace(s, key=k), x, y)
+        return s.prev_delta
+
+    us = _time(loop, reps=3)
+    rows.append(("rounds_python_loop", us, f"T={t_rounds},d={d}"))
+
     us = _time(lambda: trainer.run(state, x, y,
                                    rounds=t_rounds)[0].prev_delta, reps=3)
     rows.append(("rounds_trainer_run", us,
@@ -205,7 +186,7 @@ def bench_channel_models(rows, *, t_rounds=4):
 
     cfg = PFELSConfig(num_clients=30, clients_per_round=8, local_steps=3,
                       rounds=t_rounds)
-    params, d, _, (x, y), loss_fn, _ = _fl_problem(cfg)
+    params, d, (x, y), loss_fn = _fl_problem(cfg)
 
     for chan, tag in ((ChannelConfig(), "block_fading"),
                       (ChannelConfig(model="mimo_mrc", num_antennas=8),
@@ -235,7 +216,7 @@ def bench_sharded_round(rows):
     from repro.launch.mesh import make_cohort_mesh
 
     cfg = PFELSConfig(num_clients=30, clients_per_round=8, local_steps=3)
-    params, d, _, (x, y), loss_fn, _ = _fl_problem(cfg)
+    params, d, (x, y), loss_fn = _fl_problem(cfg)
     mesh = make_cohort_mesh(cfg.clients_per_round)
     shards = mesh.shape["pod"] * mesh.shape["data"]
 
@@ -305,7 +286,7 @@ def bench_scenarios(rows):
     from repro.launch.mesh import make_cohort_mesh
 
     cfg0 = PFELSConfig(num_clients=30, clients_per_round=8, local_steps=2)
-    params, d, _, (x, y), loss_fn, _ = _fl_problem(cfg0)
+    params, d, (x, y), loss_fn = _fl_problem(cfg0)
     mesh = make_cohort_mesh(cfg0.clients_per_round)
 
     for tag, chan_kw in _SCENARIOS:
@@ -342,7 +323,7 @@ def bench_compressors(rows):
     from repro.fl.api import replace
 
     cfg0 = PFELSConfig(num_clients=30, clients_per_round=8, local_steps=2)
-    params, d, _, (x, y), loss_fn, _ = _fl_problem(cfg0)
+    params, d, (x, y), loss_fn = _fl_problem(cfg0)
 
     variants = (
         ("rand_k", dict(compressor="rand_k")),

@@ -77,8 +77,7 @@ class ChannelModel:
           ε spend in place of the raw ``cfg.noise_std``.
       stateful(cfg) -> bool
           whether ``init`` returns real state (a config-static property;
-          the deprecated legacy shims reject stateful models — they have
-          nowhere to carry the state).
+          ``TrainState.chan`` carries that state across rounds).
       may_mask(cfg) -> bool
           whether ``step`` can return a non-None ``tx_mask`` (config-
           static, so maskless configs trace the exact seed code path).

@@ -5,14 +5,12 @@ from repro.fl.api import Trainer, TrainState
 from repro.fl.bank import (BankState, ClientBank, ResidentBank,
                            StreamedBank, make_bank)
 from repro.fl.client import local_train, model_update
-from repro.fl.rounds import (FLState, evaluate, make_round_fn,
-                             make_training_fn, round_epsilon_spent,
-                             sample_cohort, setup, split_round_key)
+from repro.fl.rounds import (evaluate, round_epsilon_spent, sample_cohort,
+                             split_round_key)
 
 __all__ = ["Algorithm", "BankState", "ClientBank", "LedgerState",
            "ResidentBank", "StreamedBank", "Trainer", "TrainState",
            "get_algorithm", "list_algorithms", "make_bank",
            "register_algorithm", "unregister_algorithm", "local_train",
-           "model_update", "FLState", "evaluate", "make_round_fn",
-           "make_training_fn", "round_epsilon_spent", "sample_cohort",
-           "setup", "split_round_key"]
+           "model_update", "evaluate", "round_epsilon_spent",
+           "sample_cohort", "split_round_key"]

@@ -4,7 +4,7 @@ Each :class:`Algorithm` entry supplies the three points where the paper's
 schemes actually differ — support selection (which coordinates are
 transmitted), β-design (the per-round power/alignment coefficient), and
 aggregation — plus the per-round privacy spend charged to the in-graph
-ledger. The round body in ``repro.fl.rounds._build_round_core`` is
+ledger. The round body in ``repro.fl.rounds._build_cohort_core`` is
 otherwise uniform: local training, error feedback, the AirComp machinery
 (unfused / fused Pallas / sharded cohort), metrics, and the server update
 are shared by every entry, so a new transmit scheme is a
@@ -122,7 +122,7 @@ def _dp_epsilon_spend(cfg: PFELSConfig, beta, d=None, *,
     """Per-round eps actually consumed (Thm 3 inverse) for the realized
     beta, capped at the configured budget — Theorem 5 already enforces
     ``C2 * beta <= eps``, so the cap only absorbs fp rounding (and matches
-    the host-side ledger convention of the legacy drivers). C2 is built
+    the host-side ``PrivacyLedger`` convention). C2 is built
     from the channel model's POST-COMBINING noise std (DESIGN.md §11):
     a multi-antenna receiver changes the intrinsic noise the guarantee
     rides on, and the ledger must charge against that operating point —

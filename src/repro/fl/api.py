@@ -5,10 +5,9 @@ registered pytree owns ALL loop state (``TrainState``): params as a pytree
 (ravel/unravel is an internal detail), the per-client ``ClientBank``
 (error-feedback residuals, PRNG lanes, participation counts —
 ``repro.fl.bank``, DESIGN.md §10), the previous round's reconstructed
-update ``prev_delta`` (the server_topk support source — previously
-smuggled through the metrics dict), the per-device power limits, the PRNG
-key, the round counter, and the in-graph privacy ledger
-(``repro.core.privacy.LedgerState``), whose (ε, δ) accumulators are
+update ``prev_delta`` (the server_topk support source), the per-device
+power limits, the PRNG key, the round counter, and the in-graph privacy
+ledger (``repro.core.privacy.LedgerState``), whose (ε, δ) accumulators are
 updated INSIDE the compiled program from the realized per-round β — so
 ``Trainer.run`` (the ``lax.scan`` path) returns exact budget totals
 without T host round-trips, and chunked resume carries the ledger
@@ -28,14 +27,11 @@ from the ``repro.fl.algorithms`` registry, so new transmit schemes plug in
 as entries, not branches.
 
 PRNG contract (also in DESIGN.md §8): ``state.key`` is the key the next
-call consumes. ``step`` uses it whole as the round key and advances it by
-``fold_in(key, 1)`` — bit-identical to the legacy
-``make_round_fn(..., key=state.key)``. ``run(T)`` splits it into T round
-keys (``jax.random.split(state.key, T)``) and advances by
-``fold_in(key, T)`` — bit-identical to the legacy ``make_training_fn``
-scan. The two schedules intentionally match their legacy counterparts, so
-``run(T)`` is NOT bitwise T repetitions of ``step`` (both are valid
-independent streams).
+call consumes. ``step`` consumes it whole as the round key and advances it
+by ``fold_in(key, 1)``. ``run(T)`` uses ``jax.random.split(state.key, T)``
+as its T round keys and advances by ``fold_in(key, T)``. So ``run(T)``
+equals T ``step`` calls fed those round keys, not T plain ``step`` calls
+(tests/test_trainer_api.py::test_run_matches_step_loop).
 """
 from __future__ import annotations
 
@@ -149,9 +145,6 @@ class Trainer:
                 "bank OR shard the cohort (DESIGN.md §10)")
         self._cohort_core = rounds._build_cohort_core(
             cfg, loss_fn, self.d, unravel, self.mesh)
-        self._core = rounds._build_round_core(
-            cfg, loss_fn, self.d, unravel, self.mesh,
-            cohort_core=self._cohort_core)
         self.step = (self._streamed_step_api
                      if self.bank.backend == "streamed"
                      else jax.jit(self._step_impl))
@@ -161,11 +154,13 @@ class Trainer:
     # ------------------------------------------------------------- state
 
     def init(self, key, params: Any = None) -> TrainState:
-        """Fresh TrainState: power limits drawn from ``key`` (the same draw
-        as the legacy ``setup``), zeroed ledger/bank/prev_delta, the
-        channel model's cross-round state initialized from its own fork of
-        ``key`` (None for stateless models), and the round-key stream
-        forked off ``key`` (never reusing the power-limit draw)."""
+        """Fresh TrainState: power limits drawn from ``key``
+        (:func:`repro.fl.rounds.init_power_limits`), zeroed
+        ledger/bank/prev_delta, the channel model's cross-round state
+        initialized from its own fork of ``key`` (None for stateless
+        models), and the round-key stream forked off ``key`` (never
+        reusing the power-limit draw). The first ``step`` consumes
+        ``state.key`` whole; ``run(T)`` uses ``split(state.key, T)``."""
         params = self._params_template if params is None else params
         return TrainState(
             params=params,
@@ -207,7 +202,7 @@ class Trainer:
     # ------------------------------------------------------------- loops
 
     def _bank_round(self, params, power_limits, bank, prev_delta, chan,
-                    data_x, data_y, round_key, t=None, eps_spent=None):
+                    data_x, data_y, round_key, t, eps_spent):
         """One round against the in-graph (resident) bank: sample the
         cohort, gather its slices, run the cohort core (which also evolves
         the channel-model carry, DESIGN.md §11), scatter the residual
@@ -407,7 +402,7 @@ class Trainer:
 
     def _streamed_step_api(self, state: TrainState, data_x, data_y=None):
         """Streamed ``step``: consumes ``state.key`` whole as the round
-        key (the resident/legacy schedule), not ``split(key, 1)``."""
+        key (the resident schedule), not ``split(key, 1)``."""
         source = loader.as_cohort_source(data_x, data_y)
         params, metrics, bank, prev_delta, ledger, chan = \
             self._streamed_rounds(state, source, state.key[None])
@@ -420,7 +415,7 @@ class Trainer:
     def ledger_totals(self, state: TrainState,
                       delta_prime: float = 1e-6) -> Dict[str, Any]:
         """Host-side (eps_T, delta_T) report from the in-graph ledger,
-        matching the legacy ``PrivacyLedger`` contract."""
+        matching the host-side ``PrivacyLedger`` contract."""
         delta = self.cfg.resolved_delta()
         return {
             "basic": privacy.ledger_totals_basic(state.ledger, delta),

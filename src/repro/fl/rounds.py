@@ -1,8 +1,10 @@
 """The full PFELS round (Alg. 2) and baselines, simulation mode.
 
-One jitted ``round_fn`` runs: sample r clients -> vmapped local training ->
-rand_k projection -> Theorem-5 power control -> AirComp over the simulated
-MAC -> server update. Baselines (WFL-P Eq. 36, WFL-PDP Eq. 37, DP-FedAvg
+``_build_cohort_core`` builds the one round body that ``repro.fl.Trainer``
+runs, as its resident ``lax.scan`` or its streamed host loop (DESIGN.md
+§8, §10): vmapped local training of the r sampled clients -> rand_k
+projection -> Theorem-5 power control -> AirComp over the simulated MAC
+-> server update. Baselines (WFL-P Eq. 36, WFL-PDP Eq. 37, DP-FedAvg
 Alg. 1, FedAvg) share the same structure with their own aggregation.
 
 Sharded cohort execution (``cfg.client_sharding="cohort"``, DESIGN.md §7):
@@ -17,17 +19,15 @@ numerically aligned with the vmapped single-device path:
      support, channel noise) happens from the SAME keys as the vmapped
      path, outside the manual region or replicated inside it;
   2. the per-client flat updates come back sharded over the cohort axis, so
-     the error-feedback scatter-back ``residuals.at[sel].set`` and all
-     metrics reuse the single-device code unchanged;
+     the bank's error-feedback scatter-back and all metrics reuse the
+     single-device code unchanged;
   3. the Theorem-5 ``beta`` is computed from the globally sampled gains
      before entering the manual region (it is a min over all r clients).
 """
 from __future__ import annotations
 
 import functools
-import warnings
-from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,9 +61,9 @@ ROUND_KEY_LANES = {
 
 
 def split_round_key(key):
-    """The per-round 7-subkey split (DESIGN.md §5) — every execution path
-    (legacy shims, Trainer resident scan, Trainer streamed loop) consumes
-    lanes from this one split."""
+    """The per-round 7-subkey split (DESIGN.md §5) — both execution paths
+    (the Trainer's resident scan and its streamed loop) consume lanes from
+    this one split."""
     return jax.random.split(key, len(ROUND_KEY_LANES))
 
 
@@ -73,33 +73,9 @@ def sample_cohort(key, n: int, r: int):
     return jax.random.choice(key, n, (r,), replace=False)
 
 
-@dataclass
-class FLState:
-    params: Any
-    power_limits: jnp.ndarray       # (N,) P_i, fixed per device
-    residuals: Optional[Any] = None  # (N, d) error-feedback memory [28-30]
-    round: int = 0
-
-
-def setup(key, params, cfg: PFELSConfig, d: int) -> FLState:
-    """DEPRECATED legacy state factory — prefer
-    ``repro.fl.Trainer(cfg, loss_fn, params).init(key)``, which returns a
-    :class:`repro.fl.api.TrainState` owning ALL loop state (params,
-    residuals, prev_delta, PRNG key, in-graph privacy ledger). This shim
-    draws the same power limits from the same key and survives only for the
-    golden-parity tests."""
-    warnings.warn(
-        "repro.fl.setup is deprecated; use repro.fl.Trainer(...).init(key) "
-        "(DESIGN.md §8)", DeprecationWarning, stacklevel=2)
-    p_lim = init_power_limits(key, cfg, d)
-    res = (jnp.zeros((cfg.num_clients, d), jnp.float32)
-           if cfg.error_feedback else None)
-    return FLState(params=params, power_limits=p_lim, residuals=res)
-
-
 def init_power_limits(key, cfg: PFELSConfig, d: int) -> jnp.ndarray:
-    """(N,) per-device power limits P_i — the one draw shared by the legacy
-    ``setup`` and ``Trainer.init`` (same key => same limits)."""
+    """(N,) per-device power limits P_i, as ``Trainer.init`` draws them
+    (same key => same limits)."""
     return channel.sample_power_limits(key, cfg.num_clients, d, cfg.channel)
 
 
@@ -235,12 +211,14 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
                        unravel: Callable, mesh: Optional[Mesh] = None):
     """The raw (un-jitted) round body on COHORT slices, uniform across
     algorithms AND channel models: ``cohort_core(params, p_sel, cx, cy,
-    ks, res_sel, prev_delta, chan_carry, sel) -> (new_params, metrics,
-    new_res_sel, delta_hat, new_chan_carry)`` where every client-indexed
-    input/output is the sampled r-client slice — ``p_sel`` (r,),
-    ``cx``/``cy`` (r, samples, ...), ``res_sel`` (r, d) or None — and
-    ``ks`` is the ``split_round_key`` output (lanes 1-6 consumed here;
-    selection/bank lanes 0 and 5 belong to the caller).
+    ks, res_sel, prev_delta, chan_carry, sel, t, eps_spent) ->
+    (new_params, metrics, new_res_sel, delta_hat, new_chan_carry)`` where
+    every client-indexed input/output is the sampled r-client slice —
+    ``p_sel`` (r,), ``cx``/``cy`` (r, samples, ...), ``res_sel`` (r, d)
+    or None — and ``ks`` is the ``split_round_key`` output (lanes 1-6
+    consumed here; selection/bank lanes 0 and 5 belong to the caller).
+    ``t`` (the absolute round counter) and ``eps_spent`` (the ledger's
+    running sum) feed the CompressionSchedule (DESIGN.md §13).
 
     The wireless scenario resolves through the ``repro.core.channels``
     registry (DESIGN.md §11): ``chan_carry`` is the model's cross-round
@@ -308,8 +286,8 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
         chunk = client_chunk(train_slice, *specs, device_memory())
         return train_in_chunks(train_slice, params, cx, cy, ck, chunk)
 
-    def support_and_beta(gains_design, p_sel, prev_delta, idx_key,
-                         t=None, eps_spent=None):
+    def support_and_beta(gains_design, p_sel, prev_delta, idx_key, t,
+                         eps_spent):
         """Registry hooks: support omega_t + β-design, from the GLOBAL (r,)
         gains — shared by both execution paths. ``gains_design`` must be
         ``channels.design_gains(cr)``: the gains the devices actually
@@ -347,6 +325,23 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
                                    epsilon=eps_t, c1_scale=c1_scale)
         return sup, beta, k_used
 
+    def transmit_prelude(flat, qkeys):
+        """The as-transmitted updates and the clip left to the aggregator,
+        ``(agg_updates, agg_clip)``, shared by both execution paths. Error
+        feedback needs the clip scales for the residual anyway (and encode
+        must see the clipped update), so compute them ONCE here and hand
+        the aggregator pre-clipped updates (clip=None) instead of paying a
+        second full (r, d) norm sweep inside the fused kernel's
+        client_sumsq pass."""
+        agg_updates, agg_clip = flat, cfg.transmit_clip
+        if pre_clip:
+            agg_updates = flat * transmit_ref.clip_scales(
+                flat, cfg.transmit_clip)[:, None]
+            agg_clip = None
+        if has_encode:
+            agg_updates = comp.encode(cfg, agg_updates, qkeys)
+        return agg_updates, agg_clip
+
     cohort_apply = None
     if n_shards > 1:
         spec_c = P(_COHORT_AXES)
@@ -365,21 +360,10 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
             tx_l = flat_l
             if aircomp:
                 with jax.named_scope("pfels.aircomp"):
-                    # same once-only clip-scale policy as the vmapped
-                    # branch: error feedback / encode need the clipped
-                    # updates anyway, so pre-apply the clip here, hand the
-                    # aggregator clip=None, and ship the as-transmitted
-                    # updates back sharded for the residual
-                    # (compressors.sparsify of tx_l == what went on the
-                    # air)
-                    agg_updates, agg_clip = flat_l, cfg.transmit_clip
-                    if pre_clip:
-                        scales_l = transmit_ref.clip_scales(
-                            flat_l, cfg.transmit_clip)
-                        agg_updates = flat_l * scales_l[:, None]
-                        agg_clip = None
-                    if has_encode:
-                        agg_updates = comp.encode(cfg, agg_updates, qk_l)
+                    # the as-transmitted updates go back sharded for the
+                    # residual (compressors.sparsify of tx_l == what went
+                    # on the air)
+                    agg_updates, agg_clip = transmit_prelude(flat_l, qk_l)
                     tx_l = agg_updates
                     delta_hat, energy, _ = \
                         aggregation.aircomp_aggregate_sharded(
@@ -409,9 +393,8 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
                       spec_c, spec_c, P(), P(), P(), P()),
             out_specs=(spec_c, spec_c, spec_c, P(), P()), check_vma=False)
 
-    def cohort_core(params, p_sel, cx, cy, ks, res_sel=None,
-                    prev_delta=None, chan_carry=None, sel=None,
-                    t=None, eps_spent=None):
+    def cohort_core(params, p_sel, cx, cy, ks, res_sel, prev_delta,
+                    chan_carry, sel, t, eps_spent):
         # each layer of the round runs under one flat ``pfels.*`` name
         # scope, which the compiled ops carry as op_name metadata into the
         # profiler's trace (PERF.md section 3 lists who reads each)
@@ -511,20 +494,8 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
                 if agg_sharded is not None:
                     delta_hat, energy = agg_sharded
                 else:
-                    # error feedback needs the clip scales for the
-                    # residual anyway (and encode must see the clipped
-                    # update), so compute them ONCE here and hand the
-                    # aggregator pre-clipped updates (clip=None) instead
-                    # of paying a second full (r, d) norm sweep inside the
-                    # fused kernel's client_sumsq pass
-                    agg_updates, agg_clip = flat_updates, cfg.transmit_clip
-                    if pre_clip:
-                        agg_updates = flat_updates * \
-                            transmit_ref.clip_scales(
-                                flat_updates, cfg.transmit_clip)[:, None]
-                        agg_clip = None
-                    if has_encode:
-                        agg_updates = comp.encode(cfg, agg_updates, qk)
+                    agg_updates, agg_clip = transmit_prelude(flat_updates,
+                                                             qk)
                     tx_full = agg_updates
                     agg_kw = dict(
                         d=d, sigma0=sigma0, r=r,
@@ -620,183 +591,6 @@ def _build_cohort_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
         return new_params, metrics, new_res_sel, delta_hat, new_chan_carry
 
     return cohort_core
-
-
-def _build_round_core(cfg: PFELSConfig, loss_fn: Callable, d: int,
-                      unravel: Callable, mesh: Optional[Mesh] = None,
-                      cohort_core: Optional[Callable] = None):
-    """Population-tensor round body — the pre-bank contract
-    ``round_core(params, power_limits, data_x, data_y, key, residuals,
-    prev_delta) -> (new_params, metrics, new_residuals, delta_hat)`` —
-    now a thin shell over :func:`_build_cohort_core`: split the round key,
-    sample the cohort (Alg. 2 line 2), gather the ``sel`` slices, run the
-    cohort core, scatter the residual slice back. Backs the deprecated
-    ``make_round_fn``/``make_training_fn`` shims (bit-identical under the
-    same key). ``cohort_core`` reuses an already-built core (the Trainer
-    shares one between its bank paths and these shims)."""
-    if cohort_core is None:
-        cohort_core = _build_cohort_core(cfg, loss_fn, d, unravel, mesh)
-    r = cfg.clients_per_round
-
-    def round_core(params, power_limits, data_x, data_y, key,
-                   residuals=None, prev_delta=None):
-        ks = split_round_key(key)
-        sel = sample_cohort(ks[ROUND_KEY_LANES["selection"]],
-                            cfg.num_clients, r)
-        res_sel = (residuals[sel]
-                   if cfg.error_feedback and residuals is not None
-                   else None)
-        # the legacy contract has nowhere to carry cross-round channel
-        # state; stateless models take carry=None (make_round_fn /
-        # make_training_fn reject stateful ones up front)
-        new_params, metrics, new_res_sel, delta_hat, _ = cohort_core(
-            params, power_limits[sel], data_x[sel], data_y[sel], ks,
-            res_sel, prev_delta, None, sel)
-        new_residuals = residuals
-        if new_res_sel is not None and residuals is not None:
-            new_residuals = residuals.at[sel].set(new_res_sel)
-        return new_params, metrics, new_residuals, delta_hat
-
-    return round_core
-
-
-def _legacy_trainer(cfg: PFELSConfig, loss_fn: Callable, d: int,
-                    unravel: Callable, mesh: Optional[Mesh]):
-    """The Trainer a legacy shim delegates to (lazy import: api.py imports
-    this module for the round core)."""
-    from repro.fl.api import Trainer
-    return Trainer(cfg, loss_fn, unravel(jnp.zeros((d,), jnp.float32)),
-                   mesh=mesh)
-
-
-def _reject_stateful_channel(cfg: PFELSConfig, shim: str):
-    """The deprecated shims carry no cross-round channel state — a
-    stateful channel model (markov_fading) would silently re-initialize
-    every round, so they refuse it; the Trainer carries it in
-    ``TrainState.chan`` (DESIGN.md §11)."""
-    model = channels.get_channel_model(cfg.channel.model)
-    if model.stateful(cfg.channel):
-        raise ValueError(
-            f"channel model {cfg.channel.model!r} is stateful and the "
-            f"deprecated {shim} has nowhere to carry its cross-round "
-            f"state; use repro.fl.Trainer (DESIGN.md §11)")
-
-
-def _reject_legacy_compression(cfg: PFELSConfig, shim: str):
-    """The deprecated shims predate the compressor registry: a
-    CompressionSchedule needs the round counter and the running ε spend
-    (which only ``TrainState`` carries), and a carry-compressor
-    (top_k_ef) needs the bank's residual memory the shim only allocates
-    under ``cfg.error_feedback`` — refuse both rather than silently
-    running a different scheme (DESIGN.md §13)."""
-    alg = algorithms.get_algorithm(cfg.algorithm)
-    if not (alg.aircomp and alg.sparsifies_transmit):
-        return
-    if compressors.schedules.is_active(cfg.schedule):
-        raise ValueError(
-            f"cfg.schedule.mode={cfg.schedule.mode!r} needs the round "
-            f"counter and privacy-ledger state that the deprecated "
-            f"{shim} has nowhere to carry; use repro.fl.Trainer "
-            f"(DESIGN.md §13)")
-    if compressors.carry_required(cfg) and not cfg.error_feedback:
-        raise ValueError(
-            f"compressor {cfg.compressor!r} requires error-feedback "
-            f"residuals but the deprecated {shim} only allocates them "
-            f"with cfg.error_feedback=True; set error_feedback=True or "
-            f"use repro.fl.Trainer (DESIGN.md §13)")
-
-
-def make_round_fn(cfg: PFELSConfig, loss_fn: Callable, d: int,
-                  unravel: Callable, mesh: Optional[Mesh] = None):
-    """DEPRECATED legacy single-round entry — a thin shim over
-    :class:`repro.fl.api.Trainer` (``Trainer.step`` is the replacement; it
-    has ONE signature and return shape regardless of config). Kept
-    bit-identical under the same key for the golden-parity tests.
-
-    loss_fn(params, {"x","y"}) -> (loss, aux). d = flat dim; unravel maps a
-    flat (d,) vector back to the params pytree. Returns
-    ``(params, metrics)`` or, with ``cfg.error_feedback``,
-    ``(params, metrics, residuals)`` — the config-dependent arity the new
-    API removes.
-
-    ``mesh``: cohort mesh for ``cfg.client_sharding="cohort"`` (defaults to
-    ``make_cohort_mesh(cfg.clients_per_round)`` over the visible devices);
-    ignored with ``client_sharding="none"``.
-    """
-    warnings.warn(
-        "repro.fl.make_round_fn is deprecated; use repro.fl.Trainer.step "
-        "(DESIGN.md §8)", DeprecationWarning, stacklevel=2)
-    _reject_stateful_channel(cfg, "make_round_fn")
-    _reject_legacy_compression(cfg, "make_round_fn")
-    trainer = _legacy_trainer(cfg, loss_fn, d, unravel, mesh)
-    core = trainer._core
-    leaks_delta_hat = (cfg.randk_mode == "server_topk"
-                       and trainer.algorithm.aircomp)
-    if leaks_delta_hat:
-        warnings.warn(
-            "the 'delta_hat' metrics key is deprecated (it stacks to a "
-            "(T, d) buffer under scan); read TrainState.prev_delta from "
-            "Trainer.step/run instead", DeprecationWarning, stacklevel=2)
-
-    def round_fn(params, power_limits, data_x, data_y, key,
-                 residuals=None, prev_delta=None):
-        new_params, metrics, new_residuals, delta_hat = core(
-            params, power_limits, data_x, data_y, key, residuals,
-            prev_delta)
-        if leaks_delta_hat:
-            metrics["delta_hat"] = delta_hat  # seed-era consumer contract
-        if cfg.error_feedback:
-            return new_params, metrics, new_residuals
-        return new_params, metrics
-
-    return jax.jit(round_fn)
-
-
-def make_training_fn(cfg: PFELSConfig, loss_fn: Callable, d: int,
-                     unravel: Callable, rounds: Optional[int] = None,
-                     mesh: Optional[Mesh] = None):
-    """DEPRECATED legacy T-round ``lax.scan`` driver — a thin shim over
-    :class:`repro.fl.api.Trainer` (``Trainer.run`` is the replacement: same
-    one-program scan, plus the in-graph privacy ledger and automatic
-    chunked-resume state). Kept bit-identical under the same key for the
-    golden-parity tests.
-
-    Returns ``training_fn(params, power_limits, data_x, data_y, key,
-    residuals=None, prev_delta=None) -> (params_T, metrics_T, residuals_T,
-    delta_T)`` where every ``metrics_T`` leaf is stacked over the T rounds
-    (leading axis T) and ``delta_T`` is the last round's reconstructed
-    update — feed it (and ``residuals_T``) back in to resume chunked
-    training. ``rounds`` defaults to ``cfg.rounds``; ``mesh`` as in
-    :func:`make_round_fn`.
-    """
-    warnings.warn(
-        "repro.fl.make_training_fn is deprecated; use repro.fl.Trainer.run "
-        "(DESIGN.md §8)", DeprecationWarning, stacklevel=2)
-    _reject_stateful_channel(cfg, "make_training_fn")
-    _reject_legacy_compression(cfg, "make_training_fn")
-    t_rounds = cfg.rounds if rounds is None else rounds
-    trainer = _legacy_trainer(cfg, loss_fn, d, unravel, mesh)
-    core = trainer._core
-
-    def training_fn(params, power_limits, data_x, data_y, key,
-                    residuals=None, prev_delta=None):
-        if cfg.error_feedback and residuals is None:
-            residuals = jnp.zeros((cfg.num_clients, d), jnp.float32)
-        if prev_delta is None:
-            prev_delta = jnp.zeros((d,), jnp.float32)
-
-        def body(carry, round_key):
-            p, res, prev = carry
-            p2, metrics, res2, delta_hat = core(
-                p, power_limits, data_x, data_y, round_key, res, prev)
-            return (p2, res2, delta_hat), metrics
-
-        keys = jax.random.split(key, t_rounds)
-        (p_final, res_final, delta_final), metrics = jax.lax.scan(
-            body, (params, residuals, prev_delta), keys)
-        return p_final, metrics, res_final, delta_final
-
-    return jax.jit(training_fn)
 
 
 def round_epsilon_spent(cfg: PFELSConfig, beta: float,

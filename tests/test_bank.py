@@ -107,8 +107,8 @@ def test_resident_streamed_bit_parity(problem, case):
 
 
 def test_streamed_step_matches_resident_step(problem):
-    """step consumes state.key whole under both backends (the resident /
-    legacy schedule, not split(key, 1))."""
+    """step consumes state.key whole under both backends (the resident
+    schedule, not split(key, 1))."""
     (tr, sr), (ts, ss) = _both_backends(
         dict(error_feedback=True), problem)
     x, y = problem[3][0], problem[3][1]

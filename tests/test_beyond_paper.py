@@ -9,7 +9,8 @@ from repro.configs import ChannelConfig, PFELSConfig
 from repro.configs.paper_models import BENCH_MLP
 from repro.core import aggregation, channel
 from repro.data import make_federated_classification
-from repro.fl import make_round_fn, setup
+from repro.fl import Trainer
+from repro.fl.api import replace
 from repro.models import cnn
 
 
@@ -59,15 +60,13 @@ def test_server_topk_round_runs_and_selects_topk():
     cfg = PFELSConfig(num_clients=20, clients_per_round=4, local_steps=2,
                       compression_ratio=0.2, epsilon=2.0, rounds=2,
                       randk_mode="server_topk")
-    state = setup(jax.random.PRNGKey(1), params, cfg, d)
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
-    prev = jnp.zeros((d,))
-    p = params
+    trainer = Trainer(cfg, loss_fn, params)
+    state = trainer.init(jax.random.PRNGKey(1))
+    assert not bool(jnp.any(state.prev_delta))
     for t in range(2):
-        p, m = fn(p, state.power_limits, x, y, jax.random.PRNGKey(t),
-                  None, prev)
-        assert "delta_hat" in m
-        prev = m["delta_hat"]
+        state, m = trainer.step(
+            replace(state, key=jax.random.PRNGKey(t)), x, y)
+        assert "delta_hat" not in m
     # the aggregated update is k-sparse on the selected coords
     k = int(round(0.2 * d))
-    assert int(jnp.sum(prev != 0)) <= k
+    assert int(jnp.sum(state.prev_delta != 0)) <= k

@@ -23,7 +23,7 @@ from repro.core.channels import (ChannelModel, ChannelRound, design_gains,
                                  realized_cohort_size,
                                  register_channel_model,
                                  unregister_channel_model)
-from repro.fl import Trainer, make_round_fn
+from repro.fl import Trainer
 from repro.fl.api import replace
 from repro.models import cnn
 
@@ -194,15 +194,6 @@ def test_markov_state_lives_in_trainstate_and_checkpoints(problem):
         a, _ = trainer.run(s1, x, y, rounds=1)
         b, _ = trainer.run(restored, x, y, rounds=1)
         assert bool(jnp.array_equal(_flat(a.params), _flat(b.params)))
-
-
-def test_stateful_model_rejected_by_legacy_shims(problem):
-    params, _, loss_fn = problem
-    d = _flat(params).shape[0]
-    cfg = PFELSConfig(**BASE, channel=ChannelConfig(model="markov_fading"))
-    with pytest.raises(ValueError, match="stateful"), \
-            pytest.deprecated_call():
-        make_round_fn(cfg, loss_fn, d, lambda f: params)
 
 
 # ------------------------------------------------------------- mimo_mrc

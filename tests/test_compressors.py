@@ -20,10 +20,8 @@ Three property families, each swept over ``list_compressors()``:
      so the power and privacy accounting agree on one C1·s.
 
 Plus registry-contract units (error messages, carry-forced error
-feedback, legacy-shim rejection, schedule algebra).
+feedback, schedule algebra).
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,7 +33,7 @@ from repro.configs.paper_models import BENCH_MLP
 from repro.core import channel, compressors, privacy
 from repro.core.compressors import schedules
 from repro.data import make_federated_classification
-from repro.fl import (Trainer, make_round_fn, round_epsilon_spent)
+from repro.fl import Trainer, round_epsilon_spent
 from repro.fl.api import replace
 from repro.models import cnn
 
@@ -253,22 +251,6 @@ def test_carry_compressor_forces_bank_residuals(problem):
     assert st.bank.residuals is not None
     st, _ = tr.step(st, x, y)
     assert float(jnp.abs(st.bank.residuals).sum()) > 0.0
-
-
-def test_legacy_shims_reject_schedule_and_carry(problem):
-    params, (x, y), loss_fn = problem
-    d = int(ravel_pytree(params)[0].shape[0])
-    unravel = ravel_pytree(params)[1]
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        with pytest.raises(ValueError, match="schedule"):
-            make_round_fn(_cfg(schedule=CompressionSchedule(mode="budget")),
-                          loss_fn, d, unravel)
-        with pytest.raises(ValueError, match="error-feedback"):
-            make_round_fn(_cfg(compressor="top_k_ef"), loss_fn, d, unravel)
-        # carry + error_feedback=True is fine through the shim
-        make_round_fn(_cfg(compressor="top_k_ef", error_feedback=True),
-                      loss_fn, d, unravel)
 
 
 def test_schedule_algebra():

@@ -7,7 +7,8 @@ from jax.flatten_util import ravel_pytree
 from repro.configs import PFELSConfig
 from repro.configs.paper_models import BENCH_MLP
 from repro.data import make_federated_classification
-from repro.fl import make_round_fn, setup
+from repro.fl import Trainer
+from repro.fl.api import replace
 from repro.models import cnn
 
 
@@ -27,15 +28,14 @@ def test_error_feedback_runs_and_accumulates():
     cfg = PFELSConfig(num_clients=20, clients_per_round=4, local_steps=3,
                       local_lr=0.05, compression_ratio=0.2, epsilon=4.0,
                       rounds=3, error_feedback=True)
-    state = setup(jax.random.PRNGKey(1), params, cfg, d)
+    trainer = Trainer(cfg, loss_fn, params)
+    state = trainer.init(jax.random.PRNGKey(1))
     assert state.residuals.shape == (20, d)
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
-    p, res = params, state.residuals
     for t in range(3):
-        p, m, res = fn(p, state.power_limits, x, y,
-                       jax.random.PRNGKey(10 + t), res)
+        state, m = trainer.step(
+            replace(state, key=jax.random.PRNGKey(10 + t)), x, y)
     # residual mass exists for the clients that participated
-    assert float(jnp.sum(jnp.abs(res))) > 0
+    assert float(jnp.sum(jnp.abs(state.residuals))) > 0
     assert jnp.isfinite(m["train_loss"])
 
 
@@ -46,10 +46,11 @@ def test_error_feedback_residual_is_untransmitted_mass():
     cfg = PFELSConfig(num_clients=20, clients_per_round=20, local_steps=2,
                       local_lr=0.05, compression_ratio=0.25, epsilon=4.0,
                       rounds=1, error_feedback=True)
-    state = setup(jax.random.PRNGKey(1), params, cfg, d)
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
-    p, m, res = fn(params, state.power_limits, x, y,
-                   jax.random.PRNGKey(0), state.residuals)
+    trainer = Trainer(cfg, loss_fn, params)
+    state = replace(trainer.init(jax.random.PRNGKey(1)),
+                    key=jax.random.PRNGKey(0))
+    state, m = trainer.step(state, x, y)
+    res = state.residuals
     k = int(round(0.25 * d))
     # every client participated; each residual has exactly d-k nonzeros
     # (up to exact zero update coords)

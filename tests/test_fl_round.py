@@ -8,7 +8,8 @@ from jax.flatten_util import ravel_pytree
 from repro.configs import PFELSConfig
 from repro.configs.paper_models import BENCH_MLP
 from repro.data import make_federated_classification
-from repro.fl import evaluate, make_round_fn, setup
+from repro.fl import Trainer, evaluate
+from repro.fl.api import replace
 from repro.models import cnn
 
 
@@ -31,9 +32,11 @@ def test_all_algorithms_run(problem, alg):
     cfg = PFELSConfig(num_clients=30, clients_per_round=4, local_steps=3,
                       local_lr=0.05, compression_ratio=0.3, epsilon=2.0,
                       rounds=2, algorithm=alg)
-    state = setup(jax.random.PRNGKey(1), params, cfg, d)
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
-    p, m = fn(params, state.power_limits, x, y, jax.random.PRNGKey(2))
+    trainer = Trainer(cfg, loss_fn, params)
+    state = replace(trainer.init(jax.random.PRNGKey(1)),
+                    key=jax.random.PRNGKey(2))
+    state, m = trainer.step(state, x, y)
+    p = state.params
     assert jnp.isfinite(m["train_loss"])
     assert not any(bool(jnp.any(jnp.isnan(l))) for l in jax.tree.leaves(p))
     if alg in ("pfels", "wfl_p", "wfl_pdp"):
@@ -50,11 +53,11 @@ def test_pfels_learns(problem):
     cfg = PFELSConfig(num_clients=30, clients_per_round=8, local_steps=5,
                       local_lr=0.05, compression_ratio=0.3, epsilon=2.0,
                       rounds=25, momentum=0.9)
-    state = setup(jax.random.PRNGKey(1), params, cfg, d)
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
+    trainer = Trainer(cfg, loss_fn, params)
+    state = trainer.init(jax.random.PRNGKey(1))
     _, acc0 = evaluate(params, loss_fn, xt, yt)
-    p = params
     for t in range(cfg.rounds):
-        p, m = fn(p, state.power_limits, x, y, jax.random.PRNGKey(100 + t))
-    _, acc1 = evaluate(p, loss_fn, xt, yt)
+        state, m = trainer.step(
+            replace(state, key=jax.random.PRNGKey(100 + t)), x, y)
+    _, acc1 = evaluate(state.params, loss_fn, xt, yt)
     assert acc1 > acc0 + 0.2, (acc0, acc1)

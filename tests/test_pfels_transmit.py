@@ -12,7 +12,8 @@ from repro.configs import PFELSConfig
 from repro.configs.paper_models import BENCH_MLP
 from repro.core import aggregation, randk
 from repro.data import make_federated_classification
-from repro.fl import make_round_fn, make_training_fn, setup
+from repro.fl import Trainer
+from repro.fl.api import replace
 from repro.kernels.pfels_transmit import ref as tref
 from repro.kernels.pfels_transmit.ops import fused_transmit
 from repro.models import cnn
@@ -333,10 +334,8 @@ def test_fused_default_round_parity_all_models(problem, model, variant):
     under error feedback + transmit clip and under imperfect CSI —
     2 Trainer.run rounds, same keys."""
     from repro.configs import ChannelConfig
-    from repro.fl import Trainer
-    from repro.fl.api import replace as st_replace
 
-    params, d, unravel, (x, y), loss_fn = problem
+    x, y = problem[3]
     chan_kw = dict(_SCENARIO_KW.get(model, {}))
     if variant == "csi":
         chan_kw["csi_error"] = 0.1
@@ -348,9 +347,7 @@ def test_fused_default_round_parity_all_models(problem, model, variant):
         import dataclasses
         cfg = dataclasses.replace(
             cfg, channel=ChannelConfig(model=model, **chan_kw))
-        trainer = Trainer(cfg, loss_fn, params)
-        state = st_replace(trainer.init(jax.random.PRNGKey(1)),
-                           key=jax.random.PRNGKey(2))
+        trainer, state = _trainer(cfg, problem)
         outs.append(trainer.run(state, x, y, rounds=2))
     (s1, m1), (s0, m0) = outs
     flat1 = ravel_pytree(s1.params)[0]
@@ -414,16 +411,26 @@ def problem():
     return params, flat.shape[0], unravel, (x, y), loss_fn
 
 
+def _trainer(cfg, problem, key=2):
+    """A Trainer on the module problem and its fresh state, power limits
+    drawn from PRNGKey(1) and the next round key pinned to ``key``."""
+    params, _, _, _, loss_fn = problem
+    trainer = Trainer(cfg, loss_fn, params)
+    state = replace(trainer.init(jax.random.PRNGKey(1)),
+                    key=jax.random.PRNGKey(key))
+    return trainer, state
+
+
 def test_round_fused_flag_parity(problem):
-    """make_round_fn(use_fused_kernel=True) == the unfused round, same key."""
-    params, d, unravel, (x, y), loss_fn = problem
+    """Trainer.step(use_fused_kernel=True) == the unfused round, same key."""
+    x, y = problem[3]
     outs = []
     for fused in (False, True):
         cfg = PFELSConfig(num_clients=30, clients_per_round=4,
                           local_steps=3, rounds=1, use_fused_kernel=fused)
-        st = setup(jax.random.PRNGKey(1), params, cfg, d)
-        fn = make_round_fn(cfg, loss_fn, d, unravel)
-        outs.append(fn(params, st.power_limits, x, y, jax.random.PRNGKey(2)))
+        trainer, state = _trainer(cfg, problem)
+        state, m = trainer.step(state, x, y)
+        outs.append((state.params, m))
     (p0, m0), (p1, m1) = outs
     for a, b in zip(jax.tree.leaves(p0), jax.tree.leaves(p1)):
         np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)
@@ -432,23 +439,20 @@ def test_round_fused_flag_parity(problem):
 
 
 def test_training_fn_matches_python_loop(problem):
-    """The lax.scan driver reproduces T sequential make_round_fn calls when
-    fed the same per-round keys."""
-    params, d, unravel, (x, y), loss_fn = problem
+    """The lax.scan driver (Trainer.run) reproduces T sequential
+    Trainer.step calls when fed the same per-round keys."""
+    x, y = problem[3]
     cfg = PFELSConfig(num_clients=30, clients_per_round=4, local_steps=2,
                       rounds=3)
-    st = setup(jax.random.PRNGKey(1), params, cfg, d)
     T = 3
-    tf = make_training_fn(cfg, loss_fn, d, unravel, rounds=T)
-    pT, ms, _, _ = tf(params, st.power_limits, x, y, jax.random.PRNGKey(7))
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
-    keys = jax.random.split(jax.random.PRNGKey(7), T)
-    p = params
+    trainer, state = _trainer(cfg, problem, key=7)
+    end, ms = trainer.run(state, x, y, rounds=T)
+    s = state
     loop_losses = []
-    for t in range(T):
-        p, m = fn(p, st.power_limits, x, y, keys[t])
+    for k in jax.random.split(state.key, T):
+        s, m = trainer.step(replace(s, key=k), x, y)
         loop_losses.append(float(m["train_loss"]))
-    for a, b in zip(jax.tree.leaves(pT), jax.tree.leaves(p)):
+    for a, b in zip(jax.tree.leaves(end.params), jax.tree.leaves(s.params)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
     assert ms["train_loss"].shape == (T,)
     np.testing.assert_allclose(np.asarray(ms["train_loss"]), loop_losses,
@@ -456,12 +460,12 @@ def test_training_fn_matches_python_loop(problem):
 
 
 def test_training_fn_error_feedback_carries_residuals(problem):
-    params, d, unravel, (x, y), loss_fn = problem
+    d, (x, y) = problem[1], problem[3]
     cfg = PFELSConfig(num_clients=30, clients_per_round=4, local_steps=2,
                       rounds=2, error_feedback=True)
-    st = setup(jax.random.PRNGKey(1), params, cfg, d)
-    tf = make_training_fn(cfg, loss_fn, d, unravel, rounds=2)
-    pT, ms, res, _ = tf(params, st.power_limits, x, y, jax.random.PRNGKey(8))
+    trainer, state = _trainer(cfg, problem, key=8)
+    end, _ = trainer.run(state, x, y, rounds=2)
+    res = end.residuals
     assert res.shape == (30, d)
     assert float(jnp.sum(jnp.abs(res))) > 0        # memory accumulated
     assert not bool(jnp.any(jnp.isnan(res)))
@@ -469,25 +473,24 @@ def test_training_fn_error_feedback_carries_residuals(problem):
 
 @pytest.mark.parametrize("alg", ["wfl_p", "dp_fedavg", "fedavg"])
 def test_training_fn_baselines_run(problem, alg):
-    params, d, unravel, (x, y), loss_fn = problem
+    x, y = problem[3]
     cfg = PFELSConfig(num_clients=30, clients_per_round=4, local_steps=2,
                       rounds=2, algorithm=alg)
-    st = setup(jax.random.PRNGKey(1), params, cfg, d)
-    tf = make_training_fn(cfg, loss_fn, d, unravel, rounds=2)
-    pT, ms, _, _ = tf(params, st.power_limits, x, y, jax.random.PRNGKey(4))
+    trainer, state = _trainer(cfg, problem, key=4)
+    end, ms = trainer.run(state, x, y, rounds=2)
     assert bool(jnp.all(jnp.isfinite(ms["train_loss"])))
-    assert not any(bool(jnp.any(jnp.isnan(l))) for l in jax.tree.leaves(pT))
+    assert not any(bool(jnp.any(jnp.isnan(l)))
+                   for l in jax.tree.leaves(end.params))
 
 
 def test_training_fn_fused_server_topk(problem):
     """server_topk carries delta_hat through the scan; fused path works."""
-    params, d, unravel, (x, y), loss_fn = problem
+    x, y = problem[3]
     cfg = PFELSConfig(num_clients=30, clients_per_round=4, local_steps=2,
                       rounds=3, randk_mode="server_topk",
                       use_fused_kernel=True)
-    st = setup(jax.random.PRNGKey(1), params, cfg, d)
-    tf = make_training_fn(cfg, loss_fn, d, unravel, rounds=3)
-    pT, ms, _, _ = tf(params, st.power_limits, x, y, jax.random.PRNGKey(5))
+    trainer, state = _trainer(cfg, problem, key=5)
+    _, ms = trainer.run(state, x, y, rounds=3)
     assert bool(jnp.all(jnp.isfinite(ms["train_loss"])))
 
 
@@ -496,78 +499,66 @@ def test_error_feedback_retains_clipped_mass(problem):
     error-feedback residual must keep (almost) the whole update — on-idx
     coordinates included — rather than treating the unclipped on-idx mass
     as sent."""
-    params, d, unravel, (x, y), loss_fn = problem
+    x, y = problem[3]
     outs = {}
     for clip in (None, 1e-9):
         cfg = PFELSConfig(num_clients=30, clients_per_round=4,
                           local_steps=3, rounds=1, error_feedback=True,
                           transmit_clip=clip)
-        st = setup(jax.random.PRNGKey(1), params, cfg, d)
-        fn = make_round_fn(cfg, loss_fn, d, unravel)
-        _, _, res = fn(params, st.power_limits, x, y,
-                       jax.random.PRNGKey(2),
-                       residuals=jnp.zeros((30, d), jnp.float32))
-        outs[clip] = float(jnp.linalg.norm(res))
+        trainer, state = _trainer(cfg, problem)
+        state, _ = trainer.step(state, x, y)
+        outs[clip] = float(jnp.linalg.norm(state.residuals))
     # clipped-to-zero transmission leaves strictly more in the memory than
     # the unclipped round (which really did send the on-idx mass)
     assert outs[1e-9] > outs[None] * 1.1, outs
 
 
 def test_training_fn_server_topk_cold_start_is_uniform(problem):
-    """Round 1 of a cold scan (zero prev_delta) must equal a cold
-    make_round_fn call (prev_delta=None) bit-for-bit: top_k over |zeros|
-    would otherwise deterministically bias the support to coords 0..k/2."""
-    params, d, unravel, (x, y), loss_fn = problem
-    cfg = PFELSConfig(num_clients=30, clients_per_round=4, local_steps=2,
-                      rounds=1, randk_mode="server_topk")
-    st = setup(jax.random.PRNGKey(1), params, cfg, d)
-    tf = make_training_fn(cfg, loss_fn, d, unravel, rounds=1)
-    p_scan, _, _, _ = tf(params, st.power_limits, x, y,
-                         jax.random.PRNGKey(3))
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
-    k0 = jax.random.split(jax.random.PRNGKey(3), 1)[0]
-    p_cold, _ = fn(params, st.power_limits, x, y, k0)
-    for a, b in zip(jax.tree.leaves(p_scan), jax.tree.leaves(p_cold)):
+    """Round 1 of a cold server_topk scan (zero prev_delta) must equal the
+    same round under plain rand-k (randk_mode="exact") bit-for-bit: top_k
+    over |zeros| would otherwise deterministically bias the support to
+    coords 0..k/2."""
+    x, y = problem[3]
+    outs = []
+    for mode in ("server_topk", "exact"):
+        cfg = PFELSConfig(num_clients=30, clients_per_round=4,
+                          local_steps=2, rounds=1, randk_mode=mode)
+        trainer, state = _trainer(cfg, problem, key=3)
+        outs.append(trainer.run(state, x, y, rounds=1)[0].params)
+    for a, b in zip(jax.tree.leaves(outs[0]), jax.tree.leaves(outs[1])):
         assert bool(jnp.all(a == b))
 
 
 def test_training_fn_stateful_scan_matches_loop_and_resumes(problem):
-    """With server_topk + error feedback: (a) the scan == a python loop
-    over make_round_fn threading (residuals, delta_hat) with the same
-    keys and the same zero-initialized state; (b) prev_delta= actually
-    changes the resumed trajectory (the carried state is consumed, so
-    chunked training does not silently reset the top-k support)."""
-    params, d, unravel, (x, y), loss_fn = problem
+    """With server_topk + error feedback: (a) run(4) == four Trainer.step
+    calls threading (residuals, prev_delta) with the same round keys from
+    the same zero-initialized state; (b) resuming from run(4) differs from
+    a cold restart with prev_delta zeroed (the carried state is consumed,
+    so chunked training does not silently reset the top-k support)."""
+    x, y = problem[3]
     cfg = PFELSConfig(num_clients=30, clients_per_round=4, local_steps=2,
                       rounds=4, randk_mode="server_topk",
                       error_feedback=True)
-    st = setup(jax.random.PRNGKey(1), params, cfg, d)
+    trainer, state = _trainer(cfg, problem, key=6)
+    full, _ = trainer.run(state, x, y, rounds=4)
 
-    tf4 = make_training_fn(cfg, loss_fn, d, unravel, rounds=4)
-    p_full, _, res_full, dh_full = tf4(params, st.power_limits, x, y,
-                                       jax.random.PRNGKey(6))
-
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
-    keys = jax.random.split(jax.random.PRNGKey(6), 4)
-    p = params
-    res = jnp.zeros((cfg.num_clients, d), jnp.float32)
-    dh = jnp.zeros((d,), jnp.float32)
-    for t in range(4):
-        p, m, res = fn(p, st.power_limits, x, y, keys[t],
-                       residuals=res, prev_delta=dh)
-        dh = m["delta_hat"]
-    for a, b in zip(jax.tree.leaves(p_full), jax.tree.leaves(p)):
+    s = state
+    for k in jax.random.split(state.key, 4):
+        s, _ = trainer.step(replace(s, key=k), x, y)
+    for a, b in zip(jax.tree.leaves(full.params), jax.tree.leaves(s.params)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(dh_full, dh, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(res_full, res, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(full.prev_delta, s.prev_delta, rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(full.residuals, s.residuals, rtol=1e-5,
+                               atol=1e-6)
 
     # (b) resuming with the carried delta differs from a cold restart
-    tf2 = make_training_fn(cfg, loss_fn, d, unravel, rounds=2)
-    warm, _, _, _ = tf2(p_full, st.power_limits, x, y,
-                        jax.random.PRNGKey(8), residuals=res_full,
-                        prev_delta=dh_full)
-    cold, _, _, _ = tf2(p_full, st.power_limits, x, y,
-                        jax.random.PRNGKey(8), residuals=res_full)
+    resumed = replace(full, key=jax.random.PRNGKey(8))
+    warm, _ = trainer.run(resumed, x, y, rounds=2)
+    cold, _ = trainer.run(
+        replace(resumed, prev_delta=jnp.zeros_like(full.prev_delta)),
+        x, y, rounds=2)
     diff = max(float(jnp.max(jnp.abs(a - b))) for a, b in
-               zip(jax.tree.leaves(warm), jax.tree.leaves(cold)))
-    assert diff > 0.0  # top-k support came from dh_full, not zeros
+               zip(jax.tree.leaves(warm.params),
+                   jax.tree.leaves(cold.params)))
+    assert diff > 0.0  # top-k support came from prev_delta, not zeros

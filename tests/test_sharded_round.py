@@ -22,7 +22,8 @@ from jax.flatten_util import ravel_pytree
 from repro.configs import PFELSConfig
 from repro.configs.paper_models import BENCH_MLP
 from repro.data import make_federated_classification
-from repro.fl import make_round_fn, make_training_fn, setup
+from repro.fl import Trainer
+from repro.fl.api import replace
 from repro.launch.mesh import cohort_shape, make_cohort_mesh, make_mesh
 from repro.models import cnn
 
@@ -76,15 +77,17 @@ def problem():
 
 
 def _run(problem, cfg, mesh=None, t_rounds=None):
-    params, d, unravel, (x, y), loss_fn = problem
-    st = setup(jax.random.PRNGKey(1), params, cfg, d)
+    """One Trainer.step (or Trainer.run over ``t_rounds``) from the same
+    fresh state: (params, metrics, residuals, prev_delta)."""
+    params, _, _, (x, y), loss_fn = problem
+    trainer = Trainer(cfg, loss_fn, params, mesh=mesh)
+    state = replace(trainer.init(jax.random.PRNGKey(1)),
+                    key=jax.random.PRNGKey(2))
     if t_rounds is not None:
-        fn = make_training_fn(cfg, loss_fn, d, unravel, rounds=t_rounds,
-                              mesh=mesh)
+        state, metrics = trainer.run(state, x, y, rounds=t_rounds)
     else:
-        fn = make_round_fn(cfg, loss_fn, d, unravel, mesh=mesh)
-    return fn(params, st.power_limits, x, y, jax.random.PRNGKey(2),
-              residuals=st.residuals)
+        state, metrics = trainer.step(state, x, y)
+    return state.params, metrics, state.residuals, state.prev_delta
 
 
 def _assert_parity(problem, extra, mesh, t_rounds=None, atol=5e-5):
@@ -111,8 +114,8 @@ def test_sharded_round_parity_fused_kernel(problem):
 
 @needs_devices
 def test_sharded_round_parity_error_feedback(problem):
-    # residuals come back as output 3 of round_fn and must match the
-    # vmapped scatter-back client-for-client
+    # the bank's residuals must match the vmapped scatter-back
+    # client-for-client
     _assert_parity(problem, dict(error_feedback=True, transmit_clip=0.5),
                    make_cohort_mesh(BASE["clients_per_round"]))
 

@@ -18,7 +18,8 @@ from jax.flatten_util import ravel_pytree
 from repro.configs import PFELSConfig, reduced_config
 from repro.configs.paper_models import BENCH_MLP
 from repro.data import make_federated_classification
-from repro.fl import evaluate, make_round_fn, setup
+from repro.fl import Trainer, evaluate
+from repro.fl.api import replace
 from repro.models import cnn, transformer as T
 
 pytestmark = pytest.mark.slow  # multi-round training / production steps
@@ -41,16 +42,15 @@ def _run(alg, fl_setting, rounds=20, p=0.3, eps=2.0, seed=11):
     cfg = PFELSConfig(num_clients=40, clients_per_round=8, local_steps=5,
                       local_lr=0.05, compression_ratio=p, epsilon=eps,
                       rounds=rounds, momentum=0.9, algorithm=alg)
-    state = setup(jax.random.PRNGKey(1), params, cfg, d)
-    fn = make_round_fn(cfg, loss_fn, d, unravel)
-    pm = params
+    trainer = Trainer(cfg, loss_fn, params)
+    state = trainer.init(jax.random.PRNGKey(1))
     energy, subc = 0.0, 0
     for t in range(rounds):
-        pm, m = fn(pm, state.power_limits, x, y,
-                   jax.random.PRNGKey(seed * 1000 + t))
+        state, m = trainer.step(
+            replace(state, key=jax.random.PRNGKey(seed * 1000 + t)), x, y)
         energy += float(m["energy"])
         subc = int(m["subcarriers"])
-    _, acc = evaluate(pm, loss_fn, xt, yt)
+    _, acc = evaluate(state.params, loss_fn, xt, yt)
     return acc, energy, subc
 
 

@@ -1,27 +1,22 @@
-"""The unified Trainer/TrainState API (DESIGN.md §8): golden parity against
-the legacy ``make_round_fn``/``make_training_fn`` shims under identical
-keys, in-graph ledger totals vs the host-side ``PrivacyLedger``, uniform
-signatures, chunked resume, and algorithm-registry round-trip."""
-import warnings
-
+"""The unified Trainer/TrainState API (DESIGN.md §8): ``run(T)`` equals T
+``step`` calls fed its round keys, in-graph ledger totals vs the host-side
+``PrivacyLedger``, uniform signatures, chunked resume, and
+algorithm-registry round-trip."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.flatten_util import ravel_pytree
 
-from repro.configs import PFELSConfig
+from repro.configs import ChannelConfig, CompressionSchedule, PFELSConfig
 from repro.configs.paper_models import BENCH_MLP
 from repro.core import privacy
 from repro.data import make_federated_classification
-from repro.fl import (Algorithm, Trainer, make_round_fn, make_training_fn,
-                      register_algorithm, round_epsilon_spent, setup,
-                      unregister_algorithm)
+from repro.fl import (Algorithm, Trainer, register_algorithm,
+                      round_epsilon_spent, rounds, unregister_algorithm)
 from repro.fl.api import replace
-from repro.launch.mesh import make_cohort_mesh
 from repro.models import cnn
 
-MULTI = len(jax.devices()) >= 2
 BASE = dict(num_clients=20, clients_per_round=4, local_steps=2,
             local_lr=0.05, compression_ratio=0.3, epsilon=2.0, rounds=2)
 
@@ -42,21 +37,9 @@ def _flat(p):
     return ravel_pytree(p)[0]
 
 
-def _legacy(cfg, problem, mesh=None):
-    """(round_fn, training_fn(T=3), legacy FLState) with warnings silenced
-    — the shims are deprecated by design and these are the parity tests."""
-    params, d, unravel, _, loss_fn = problem
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        fn = make_round_fn(cfg, loss_fn, d, unravel, mesh=mesh)
-        tf = make_training_fn(cfg, loss_fn, d, unravel, rounds=3, mesh=mesh)
-        st = setup(jax.random.PRNGKey(1), params, cfg, d)
-    return fn, tf, st
-
-
-def _trainer_state(cfg, problem, mesh=None):
-    params, d, unravel, _, loss_fn = problem
-    trainer = Trainer(cfg, loss_fn, params, mesh=mesh)
+def _trainer_state(cfg, problem):
+    params, _, _, _, loss_fn = problem
+    trainer = Trainer(cfg, loss_fn, params)
     state = replace(trainer.init(jax.random.PRNGKey(1)),
                     key=jax.random.PRNGKey(2))
     return trainer, state
@@ -74,77 +57,56 @@ PARITY_CASES = {
 }
 
 
-# dp_fedavg's server noise moves every parameter by O(1). The two
-# separately jitted programs (Trainer.step and the legacy round) let XLA
-# round the noisy update differently in the last bit, so their params may
-# differ by a few ulps of the larger of each parameter's start and end
-# magnitudes; every metric still matches bitwise.
-ULP_CASES = {"dp_fedavg"}
-MAX_ULP = 4
+# PARITY_CASES plus the configurations whose state crosses rounds in a
+# carry of its own: the channel model's (markov_fading), the round counter
+# and the ledger's running spend that feed the schedule (budget_schedule),
+# and the bank residuals a carry compressor forces on (top_k_ef).
+RUN_CASES = {
+    **PARITY_CASES,
+    "markov_fading": dict(channel=ChannelConfig(model="markov_fading")),
+    "budget_schedule": dict(schedule=CompressionSchedule(
+        mode="budget", k_end_ratio=0.5, power_end=0.5)),
+    "top_k_ef": dict(compressor="top_k_ef"),
+}
 
 
-def _assert_params_match(case, got, want, start):
-    got, want = np.asarray(_flat(got)), np.asarray(_flat(want))
-    if case not in ULP_CASES:
-        assert bool(np.array_equal(got, want))
-        return
-    scale = np.maximum(np.abs(np.asarray(_flat(start))), np.abs(want))
-    assert np.all(np.abs(got - want) <= MAX_ULP * np.spacing(scale))
+def _assert_same(got, want, what):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                    strict=True):
+        assert bool(jnp.array_equal(g, w)), what
 
 
-@pytest.mark.parametrize("case", sorted(PARITY_CASES))
-def test_step_and_run_match_legacy_bitwise(problem, case):
-    """Trainer.step == legacy make_round_fn and Trainer.run == legacy
-    make_training_fn, bit-for-bit under the same PRNG key, for every
-    registered paper algorithm and execution option (params of the
-    ``ULP_CASES`` to a few ulps, see above)."""
-    cfg = PFELSConfig(**BASE, **PARITY_CASES[case])
-    d = problem[1]
-    x, y = problem[3][0], problem[3][1]
-    fn, tf, legacy_st = _legacy(cfg, problem)
+@pytest.mark.parametrize("case", sorted(RUN_CASES))
+def test_run_matches_step_loop(problem, case):
+    """Trainer.run(T=3) == three Trainer.step calls fed
+    ``split(state.key, 3)``, bit-for-bit: params, prev_delta, residuals,
+    channel carry, every stacked metric, the ledger and the round
+    counter."""
+    params, d, _, (x, y, _, _), loss_fn = problem
+    cfg = PFELSConfig(**BASE, **RUN_CASES[case])
     trainer, state = _trainer_state(cfg, problem)
+    assert bool(jnp.array_equal(
+        trainer.init(jax.random.PRNGKey(1)).power_limits,
+        rounds.init_power_limits(jax.random.PRNGKey(1), cfg, d)))
 
-    # power limits: init(key) draws what setup(key) drew
-    assert bool(jnp.array_equal(state.power_limits, legacy_st.power_limits))
-
-    # single round: step consumes state.key exactly like round_fn(key=...)
-    out = fn(state.params, legacy_st.power_limits, x, y,
-             jax.random.PRNGKey(2), legacy_st.residuals,
-             jnp.zeros((d,), jnp.float32))
-    new_state, metrics = trainer.step(state, x, y)
-    _assert_params_match(case, new_state.params, out[0], state.params)
-    for k in ("train_loss", "beta", "energy", "subcarriers"):
-        assert bool(jnp.array_equal(metrics[k], out[1][k])), k
-    if cfg.error_feedback:
-        assert bool(jnp.array_equal(new_state.residuals, out[2]))
-
-    # T rounds: run splits state.key exactly like the legacy scan driver
-    pT, mT, resT, deltaT = tf(state.params, legacy_st.power_limits, x, y,
-                              jax.random.PRNGKey(2), legacy_st.residuals)
     run_state, run_metrics = trainer.run(state, x, y, rounds=3)
-    _assert_params_match(case, run_state.params, pT, state.params)
-    assert bool(jnp.array_equal(run_state.prev_delta, deltaT))
-    assert bool(jnp.array_equal(run_metrics["train_loss"],
-                                mT["train_loss"]))
-    if cfg.error_feedback:
-        assert bool(jnp.array_equal(run_state.residuals, resT))
-    assert int(run_state.round) == 3
+    s, step_metrics = state, []
+    for round_key in jax.random.split(state.key, 3):
+        s, m = trainer.step(replace(s, key=round_key), x, y)
+        step_metrics.append(m)
 
-
-@pytest.mark.skipif(not MULTI, reason="needs >= 2 host devices (the CI "
-                    "docs job forces 8)")
-def test_trainer_matches_legacy_under_cohort_sharding(problem):
-    """The sharded cohort path through the Trainer equals the sharded
-    legacy path bitwise (both route the identical core)."""
-    cfg = PFELSConfig(**BASE, client_sharding="cohort")
-    mesh = make_cohort_mesh(cfg.clients_per_round)
-    x, y = problem[3][0], problem[3][1]
-    fn, _, legacy_st = _legacy(cfg, problem, mesh=mesh)
-    trainer, state = _trainer_state(cfg, problem, mesh=mesh)
-    pL, _ = fn(state.params, legacy_st.power_limits, x, y,
-               jax.random.PRNGKey(2))
-    new_state, _ = trainer.step(state, x, y)
-    assert bool(jnp.array_equal(_flat(new_state.params), _flat(pL)))
+    _assert_same(run_state.params, s.params, "params")
+    _assert_same(run_state.prev_delta, s.prev_delta, "prev_delta")
+    _assert_same(run_state.bank, s.bank, "bank")
+    _assert_same(run_state.chan, s.chan, "chan")
+    _assert_same(run_state.ledger, s.ledger, "ledger")
+    assert set(run_metrics) == set(step_metrics[0])
+    for k in run_metrics:
+        _assert_same(run_metrics[k],
+                     jnp.stack([m[k] for m in step_metrics]), k)
+    assert int(run_state.round) == int(s.round) == 3
+    assert bool(jnp.array_equal(run_state.key,
+                                jax.random.fold_in(state.key, 3)))
 
 
 def test_uniform_signature_and_no_metrics_leak(problem):
@@ -164,17 +126,6 @@ def test_uniform_signature_and_no_metrics_leak(problem):
             k = max(int(round(cfg.compression_ratio * trainer.d)), 1)
             assert int(jnp.sum(state.prev_delta != 0)) <= k
     assert len(keysets) == 1   # the fixed metrics contract
-
-
-def test_legacy_shims_warn_and_leak_behind_deprecation(problem):
-    params, d, unravel, (x, y, _, _), loss_fn = problem
-    cfg = PFELSConfig(**BASE, randk_mode="server_topk")
-    with pytest.deprecated_call():
-        fn = make_round_fn(cfg, loss_fn, d, unravel)
-    with pytest.deprecated_call():
-        st = setup(jax.random.PRNGKey(1), params, cfg, d)
-    _, m = fn(params, st.power_limits, x, y, jax.random.PRNGKey(2))
-    assert "delta_hat" in m   # seed-era contract, kept behind the warning
 
 
 def test_in_graph_ledger_matches_host_ledger(problem):
